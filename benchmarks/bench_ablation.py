@@ -15,8 +15,8 @@ def run(out=print):
     out("dataset,method,ef,recall,ndist,us_per_query,qps")
     for dataset in ("SYN-EASY", "SYN-HARD"):
         x, attrs, queries = C.get_dataset(dataset)
-        idx_full = C.index_to_device(C.get_index(dataset)[0])
-        idx_g1 = C.index_to_device(C.get_index(dataset, nlist=1)[0])
+        idx_full = C.get_index(dataset)[0]
+        idx_g1 = C.get_index(dataset, nlist=1)[0]
         pred = C.make_workload(rng, C.N_QUERIES, 0.3, 1, disj=False)
         truth = C.ground_truth(x, attrs, queries, pred)
         for method, idx in (

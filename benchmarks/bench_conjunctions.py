@@ -10,7 +10,7 @@ from . import common as C
 
 def run(target_recall: float = 0.9, dataset: str = "SYN-EASY", out=print):
     idx_host, _ = C.get_index(dataset)
-    idx = C.index_to_device(idx_host)
+    idx = idx_host
     x, attrs, queries = C.get_dataset(dataset)
     rng = np.random.default_rng(0)
     out(f"# conjunctions dataset={dataset} target_recall={target_recall}")

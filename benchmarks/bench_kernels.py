@@ -24,8 +24,9 @@ The committed baseline records the CPU-interpret numbers to keep the
 trajectory attributable; ``meta.backend``/``platform`` say which regime
 a given artifact measured.
 
-The final row snapshots the autotuner's measured block table
-(``kernels/autotune.snapshot``) so an artifact records *which* block
+The final row records the autotuner's decisions
+(``kernels/autotune.decisions``: each shape's block config and whether it
+was pinned, measured or the default) so an artifact records *which* block
 configs produced its numbers.
 
 ``python -m benchmarks.bench_kernels --selfcheck`` runs the fallback
@@ -42,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import autotune, ops
+from repro.kernels import autotune, ops, ref
 
 D_SWEEP = (16, 48)
 V_SWEEP = (64, 256)
@@ -146,12 +147,12 @@ def _pq_bench(rng, m: int, metric: str, v: int = 256, ks: int = 16) -> dict:
     def make(use_pallas):
         @jax.jit
         def f(qs, ids):
+            luts = jax.vmap(lambda q1: ref.adc_lut(codebooks, q1, metric))(qs)
             return jax.vmap(
-                lambda q1, i1, m1: ops.pq_score(
-                    codes, attrs, i1, m1, q1, codebooks, lo, hi,
-                    metric=metric, use_pallas=use_pallas,
+                lambda t1, i1, m1: ops.pq_score(
+                    codes, attrs, i1, m1, t1, lo, hi, use_pallas=use_pallas
                 )
-            )(qs, ids, mask)
+            )(luts, ids, mask)
 
         return f
 
@@ -262,11 +263,11 @@ def run(out=print):
                 f"ivf_score,{metric},{row['d']},{nlist},-,"
                 f"{row['pallas']['qps']:.1f},{row['ref']['qps']:.1f}"
             )
-    # provenance: which block configs the autotuner measured/selected for
-    # the numbers above (empty when pinned or measurement-disabled)
+    # provenance: which block configs produced the numbers above, and
+    # where each came from (pin / table / measured / default)
     rows.append(
         {"kernel": "autotune_table", "metric": "-", "d": 0, "v": 0,
-         "table": autotune.snapshot()}
+         "table": autotune.decisions()}
     )
     return rows
 

@@ -44,7 +44,7 @@ def _mode_counts(res) -> dict:
 
 def run(dataset: str = "SYN-EASY", out=print):
     idx_host, _ = C.get_index(dataset)
-    idx = C.index_to_device(idx_host)
+    idx = idx_host
     x, attrs, queries = C.get_dataset(dataset)
     qj = jnp.asarray(queries)
     rng = np.random.default_rng(0)

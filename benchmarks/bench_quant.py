@@ -34,7 +34,6 @@ from repro.core.quant import (
     QuantParams,
     build_luts,
     quantize_index,
-    residual_queries,
 )
 from repro.compass import CompassParams, compass_search
 
@@ -80,8 +79,7 @@ def _scan_microbench(qidx, queries, pred, backend, metric="l2", reps: int = 5):
     @jax.jit
     def adc(qs):
         luts = build_luts(qidx.qvecs, qs, metric)
-        qr = residual_queries(qidx.qvecs, qs)
-        d, p = backend.scan_scores_quantized(qidx, qr, luts, pred, ids, mask, metric)
+        d, p = backend.scan_scores_quantized(qidx, luts, pred, ids, mask, metric)
         return d, p
 
     @jax.jit
@@ -114,11 +112,11 @@ def run(dataset: str = "SYN-EASY", out=print):
     truths = {}
     for name, pred in workloads:
         truths[name] = C.ground_truth(x, attrs, queries, pred)
-        res, wall = _timed(C.index_to_device(idx_host), qj, pred, pm_exact)
+        res, wall = _timed(idx_host, qj, pred, pm_exact)
         exact_runs[name] = (res, C._finish("exact", EF, res, truths[name], C.N, wall))
     for m in M_SWEEP:
         qidx = quantize_index(
-            C.index_to_device(idx_host), QuantConfig(m=m, iters=KMEANS_ITERS)
+            idx_host, QuantConfig(m=m, iters=KMEANS_ITERS)
         )
         bpv = qidx.qvecs.bytes_per_vector
         for name, pred in workloads:

@@ -57,7 +57,7 @@ def _percentile(xs, p):
 
 def run(dataset: str = "SYN-EASY", out=print):
     idx_host, _ = C.get_index(dataset)
-    idx = C.index_to_device(idx_host)
+    idx = idx_host
     _, _, queries = C.get_dataset(dataset)
     rng = np.random.default_rng(11)
     pm = CompassParams(k=C.K, ef=EF, backend=C.BACKEND)

@@ -8,13 +8,13 @@ and the primary hardware-independent metric is #Comp (vector distance
 computations), exactly as the paper argues.  Set REPRO_BENCH_N/REPRO_BENCH_D
 to rescale.
 
-Indices are built once and cached on disk (benchmarks/.cache).
+Indices are built from the dataset seed once per process.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
-import pickle
 import time
 
 import jax
@@ -27,7 +27,6 @@ from repro.core.index import BuildConfig, build_index
 from repro.compass import CompassParams, compass_search
 from repro.data.synthetic import make_vector_corpus
 
-CACHE = os.path.join(os.path.dirname(__file__), ".cache")
 N = int(os.environ.get("REPRO_BENCH_N", 60000))
 D = int(os.environ.get("REPRO_BENCH_D", 48))
 N_ATTRS = 4
@@ -53,7 +52,9 @@ def bench_metadata() -> dict:
         # row in the file.
         "backend_applies_to": ["compass*", "navix", "postfilter"],
         "jax_version": jax.__version__,
-        "platform": jax.default_backend(),
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": len(jax.devices()),
         "n": N,
         "d": D,
         # full-precision per-row footprint; the quantized tier's figures
@@ -81,29 +82,14 @@ def get_dataset(name: str):
     return x, attrs, queries[:N_QUERIES]
 
 
+@functools.cache
 def get_index(name: str, nlist: int = 128, m: int = 16):
-    os.makedirs(CACHE, exist_ok=True)
-    path = os.path.join(CACHE, f"{name}_n{N}_d{D}_m{m}_nl{nlist}.pkl")
-    if os.path.exists(path):
-        with open(path, "rb") as f:
-            idx_host, build_s = pickle.load(f)
-        # caches written before the planner existed lack attribute stats;
-        # rebuild so planner benches don't fail on a stale pickle
-        if getattr(idx_host, "astats", None) is not None:
-            return idx_host, build_s
-        os.remove(path)
+    """The dataset's index, built from its seed once per process (never
+    loaded from disk: a stored build would not follow code changes)."""
     x, attrs, _ = get_dataset(name)
     t0 = time.time()
     idx = build_index(x, attrs, BuildConfig(m=m, nlist=nlist))
-    build_s = time.time() - t0
-    idx_host = jax.tree.map(np.asarray, idx)
-    with open(path, "wb") as f:
-        pickle.dump((idx_host, build_s), f)
-    return idx_host, build_s
-
-
-def index_to_device(idx_host):
-    return jax.tree.map(jnp.asarray, idx_host)
+    return idx, time.time() - t0
 
 
 def make_workload(rng, n_queries: int, passrate: float, n_terms: int, disj: bool):

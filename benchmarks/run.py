@@ -176,7 +176,10 @@ def main() -> None:
         os.environ.setdefault("REPRO_BENCH_N", "20000")
         os.environ.setdefault("REPRO_BENCH_Q", "32")
     names = [args.only] if args.only else list(ALL)
+    from repro.compile_cache import configure as configure_compile_cache
     from repro.obs import profiling as obs_prof
+
+    print(f"==== compile cache -> {configure_compile_cache()} ====", flush=True)
     from repro.obs import timeseries as obs_ts
 
     # one registry snapshot before the first bench and after each one, so

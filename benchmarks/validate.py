@@ -97,7 +97,7 @@ ROW_REQUIRED = {
     # (workload == "scan") add adc_scan/exact_scan QPS instead
     "bench_quant": ("workload", "m", "refine_factor", "bytes_per_vector"),
     # visit_step rows add fused/unfused qps arms, pq/ivf rows pallas/ref
-    # arms; the trailing autotune_table row carries the measured block table
+    # arms; the trailing autotune_table row carries the tuner's decisions
     "bench_kernels": ("kernel", "metric", "d", "v"),
     # off/on/explain arms plus a summary row with the overhead fraction
     "bench_obs": ("arm", "qps"),

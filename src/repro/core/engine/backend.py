@@ -95,21 +95,19 @@ class VisitBackend(Protocol):
         per-query vmap so the pallas path gets one blocked problem."""
         ...
 
-    def adc_scores(self, index, q_resid, lut, pred, safe_ids, mask, metric):
-        """Quantized visit scoring: distances come from the per-query ADC
-        table over ``index.qvecs`` codes instead of the float32 rows.
-        ``q_resid`` is the centered zero-padded query (consumed by the
-        pallas kernel's fused LUT construction), ``lut`` the precomputed
-        (m, ks) table (consumed by the jnp path) — same math, one source
-        (kernels.ref.subspace_lut).  Sentinel ids are masked-out slots
-        even under a true mask.  Returns (dist (V,), passing (V,))."""
+    def adc_scores(self, index, lut, pred, safe_ids, mask, metric):
+        """Quantized visit scoring: distances come from the query's (m, ks)
+        ADC table (quant/encode.build_luts, which carries the metric) over
+        ``index.qvecs`` codes instead of the float32 rows — one table for
+        both backends.  Sentinel ids are masked-out slots even under a
+        true mask.  Returns (dist (V,), passing (V,))."""
         ...
 
-    def scan_scores_quantized(self, index, q_resid, luts, pred, ids, mask, metric):
+    def scan_scores_quantized(self, index, luts, pred, ids, mask, metric):
         """Batched quantized scan — scan_scores over PQ codes: (B, V) ids,
-        (B, d_pad) residual queries, (B, m, ks) tables.  Serves the
-        planner's PREFILTER materialization and the mutable delta brute
-        scan when the quantized tier is active."""
+        (B, m, ks) tables.  Serves the planner's PREFILTER materialization
+        and the mutable delta brute scan when the quantized tier is
+        active."""
         ...
 
 
@@ -164,7 +162,7 @@ class RefBackend:
         )(pred.lo, pred.hi, attrs)
         return dist, passing & valid
 
-    def adc_scores(self, index, q_resid, lut, pred, safe_ids, mask, metric):
+    def adc_scores(self, index, lut, pred, safe_ids, mask, metric):
         from ...kernels.ref import chain_sum_m
 
         qv = index.qvecs
@@ -178,7 +176,7 @@ class RefBackend:
         passing = P.evaluate(pred, attrs) & valid
         return dist, passing
 
-    def scan_scores_quantized(self, index, q_resid, luts, pred, ids, mask, metric):
+    def scan_scores_quantized(self, index, luts, pred, ids, mask, metric):
         from ...kernels.ref import chain_sum_m
 
         qv = index.qvecs
@@ -281,33 +279,21 @@ class PallasBackend:
         )
         return dist, passing & mask
 
-    def adc_scores(self, index, q_resid, lut, pred, safe_ids, mask, metric):
-        # the pq_score kernel builds the LUT in-kernel from q_resid (the
-        # fused path); precomputed tables only feed the jnp path
-        if metric not in self._KERNEL_METRICS:
-            self._metric_fallback("pq_score", metric)
-            return RefBackend().adc_scores(index, q_resid, lut, pred, safe_ids, mask, metric)
+    def adc_scores(self, index, lut, pred, safe_ids, mask, metric):
+        # the table already carries the metric, so every metric the table
+        # builder accepts runs on the kernel
         from ...kernels import ops
 
-        qv = index.qvecs
         dist, passing = ops.pq_score(
-            qv.codes, index.attrs, safe_ids, mask, q_resid, qv.codebooks,
-            pred.lo, pred.hi, metric=metric,
+            index.qvecs.codes, index.attrs, safe_ids, mask, lut, pred.lo, pred.hi
         )
         return dist, passing & mask
 
-    def scan_scores_quantized(self, index, q_resid, luts, pred, ids, mask, metric):
-        if metric not in self._KERNEL_METRICS:
-            self._metric_fallback("pq_score", metric)
-            return RefBackend().scan_scores_quantized(
-                index, q_resid, luts, pred, ids, mask, metric
-            )
+    def scan_scores_quantized(self, index, luts, pred, ids, mask, metric):
         from ...kernels import ops
 
-        qv = index.qvecs
         dist, passing = ops.pq_score_batch(
-            qv.codes, index.attrs, ids, mask, q_resid, qv.codebooks,
-            pred.lo, pred.hi, metric=metric,
+            index.qvecs.codes, index.attrs, ids, mask, luts, pred.lo, pred.hi
         )
         return dist, passing & mask
 
@@ -318,7 +304,7 @@ class QuantAdapter:
 
     The driver instantiates one per query (inside the vmap) when
     ``CompassParams.quant`` is active, capturing that query's precomputed
-    (m, ks) table and centered residual; the iterators and ``state.visit``
+    (m, ks) table; the iterators and ``state.visit``
     keep calling the ordinary ``visit_scores`` surface, so candidate
     generation is untouched — exactly the generation/scoring split the
     backend layer exists for.  ``counts_as`` routes the work into
@@ -327,22 +313,19 @@ class QuantAdapter:
 
     counts_as = "adc"
 
-    def __init__(self, inner: VisitBackend, lut, q_resid):
+    def __init__(self, inner: VisitBackend, lut):
         self.inner = inner
         self.name = inner.name
         self.lut = lut
-        self.q_resid = q_resid
 
     def visit_scores(self, index, q, pred, safe_ids, mask, metric):
-        return self.inner.adc_scores(
-            index, self.q_resid, self.lut, pred, safe_ids, mask, metric
-        )
+        return self.inner.adc_scores(index, self.lut, pred, safe_ids, mask, metric)
 
     def visit_step(
         self, index, q, pred, safe_ids, mask, metric, fused=True, rows_per_step=None
     ):
-        # ADC scoring stays a separate kernel (pq_score builds the LUT in
-        # scratch); the tombstone AND + admission select compose here —
+        # ADC scoring stays a separate kernel (pq_score); the tombstone
+        # AND + admission select compose here —
         # both inner backends produce parity-tested (dist, passing), so the
         # composed admit inherits the parity
         dist, passing = self.visit_scores(index, q, pred, safe_ids, mask, metric)

@@ -224,7 +224,6 @@ def _search_one(
     needs_rank: bool = True,
     plan: "qplan.PlannedBatch | None" = None,
     lut=None,
-    q_resid=None,
 ) -> SearchResult:
     n = index.n_records
     nlist = index.nlist
@@ -233,7 +232,7 @@ def _search_one(
     if lut is not None:
         # quantized tier: route VISIT scoring through this query's ADC
         # table; candidate generation (iterators, queues) is untouched
-        backend = QuantAdapter(backend, lut, q_resid)
+        backend = QuantAdapter(backend, lut)
 
     # B.OPEN / G.OPEN: exact centroid ranking shared by the relational
     # iterator and the adaptive entry.  `cdists` is computed batched in
@@ -333,7 +332,7 @@ def _search_one(
                 s = btree_iter.step(index, q, pred, chosen, s, pm, backend)
                 return S.credit(s, max(1, pm.k // 2))  # Alg. 3 line 20: k/2 batch
 
-            st = jax.lax.cond(need_b & ~st.b_exhausted, do_b, lambda s: s, st)
+            st = S.run_if(need_b & ~st.b_exhausted, do_b, st)
         # stall: nothing can make progress anymore
         graph_dead = graph_iter.dead(st, pm) if pm.use_graph else jnp.asarray(True)
         stalled = graph_dead & st.b_exhausted
@@ -357,7 +356,6 @@ def compass_search_jit(
     pred: P.Predicate,
     pm: CompassParams,
     luts: jax.Array | None = None,
-    q_resids: jax.Array | None = None,
 ) -> SearchResult:
     """The jitted search program behind :func:`compass_search` — use it
     directly for AOT paths (``.lower(...).compile()``, the serving
@@ -367,9 +365,9 @@ def compass_search_jit(
     quantized search: stage one runs the ordinary loop at
     ``ef * refine_factor`` with ADC scoring, stage two reranks the
     survivors exactly and returns the top ``pm.k`` (quant/rerank.py).
-    ``luts``/``q_resids`` optionally supply the per-query ADC tables and
-    centered residuals (built here when omitted) — the mutable fan-out
-    passes its own so base and delta share one table build per query.
+    ``luts`` optionally supplies the per-query ADC tables (built here when
+    omitted) — the mutable fan-out passes its own so base and delta share
+    one table build per query.
     """
     if pm.metric == "cos":
         # cosine == inner product over unit-norm rows: normalize the query
@@ -406,28 +404,25 @@ def compass_search_jit(
     else:
         cdists = jnp.zeros((queries.shape[0], index.nlist), jnp.float32)
     if quant:
-        # per-query ADC tables, built batched outside the vmap; derived
-        # independently so a caller supplying one of the pair still works
+        # per-query ADC tables, built batched outside the vmap
         if luts is None:
             luts = Q.build_luts(index.qvecs, queries, pm.metric)  # (B, m, ks)
-        if q_resids is None:
-            q_resids = Q.residual_queries(index.qvecs, queries)  # (B, d_pad)
     else:
-        luts = q_resids = None
+        luts = None
     planned = (
-        qplan.plan_batch(index, queries, pred, pm, backend, luts=luts, q_resids=q_resids)
+        qplan.plan_batch(index, queries, pred, pm, backend, luts=luts)
         if pm.planner
         else None
     )
     # one vmap for all planner x quant combinations: None is a leafless
-    # pytree, so an absent plan / lut / residual passes through the batch
-    # axes untouched and _search_one's trace-time `is None` branches see
+    # pytree, so an absent plan / lut passes through the batch axes
+    # untouched and _search_one's trace-time `is None` branches see
     # exactly what a narrower call signature would have passed
     res = jax.vmap(
-        lambda q, cd, lo, hi, pl, lut, qr: _search_one(
-            index, q, cd, P.Predicate(lo, hi), pm, backend, needs_rank, pl, lut, qr
+        lambda q, cd, lo, hi, pl, lut: _search_one(
+            index, q, cd, P.Predicate(lo, hi), pm, backend, needs_rank, pl, lut
         )
-    )(queries, cdists, pred.lo, pred.hi, planned, luts, q_resids)
+    )(queries, cdists, pred.lo, pred.hi, planned, luts)
     if quant:
         res = rerank_batch(
             index, queries, pred, res, k_out, pm.metric, backend, pm.quant.rerank
@@ -441,7 +436,6 @@ def compass_search(
     pred: P.Predicate,
     pm: CompassParams,
     luts: jax.Array | None = None,
-    q_resids: jax.Array | None = None,
     *,
     explain: bool = False,
 ):
@@ -463,7 +457,7 @@ def compass_search(
     under an outer ``jax.jit`` (the default ``False`` path is
     transparent to tracing — ``mutable_search`` relies on that).
     """
-    res = compass_search_jit(index, queries, pred, pm, luts, q_resids)
+    res = compass_search_jit(index, queries, pred, pm, luts)
     if not explain:
         return res
     from repro.obs.trace import build_traces  # lazy: obs sits above the engine

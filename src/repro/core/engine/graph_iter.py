@@ -100,9 +100,7 @@ def step(index, q, pred, st: S.EngineState, pm, backend):
     at_cap = st.efs >= pm.ef_cap
     st = st._replace(efs=jnp.where(gstop & ~at_cap, new_efs, st.efs))
     do_pop = ~gstop
-    st = jax.lax.cond(
-        do_pop, lambda s: expand(index, q, pred, s, pm, backend), lambda s: s, st
-    )
+    st = S.run_if(do_pop, lambda s: expand(index, q, pred, s, pm, backend), st)
     low_sel = do_pop & (st.last_sel < pm.beta)
     # low-sel break is also a G.NEXT round boundary (Alg. 2 line 17)
     st = jax.lax.cond(low_sel, lambda s: S.credit(s, pm.k), lambda s: s, st)

@@ -179,6 +179,19 @@ def visit(index, q, pred, st: EngineState, ids, mask, pm, backend) -> EngineStat
     )
 
 
+def run_if(pred, f, st: EngineState) -> EngineState:
+    """``lax.cond(pred, f, identity, st)`` for a per-lane ``pred``, as a
+    select.  The engine runs under ``vmap``, where a cond with a batched
+    predicate runs both branches and selects anyway — but its batching
+    rule first broadcasts every operand to the batch, the index included.
+    A Pallas kernel takes the corpus as an HBM operand, so that broadcast
+    would be materialized: B copies of the corpus.  Running ``f`` and
+    selecting computes the same values and keeps the index unbatched.
+    Use it for the branches that score rows (they reach the kernels)."""
+    new = f(st)
+    return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, st)
+
+
 def res_count(st: EngineState) -> jax.Array:
     return st.res.count()
 

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.events import timed
+
 from .distances import pairwise
 from .kmeans import kmeans
 
@@ -422,19 +424,27 @@ def build_graph(
     n, d = x.shape
     n_candidates = n_candidates or max(2 * m, 16)
     n_build_clusters = n_build_clusters or max(8, min(n // 128, 4096))
-    km = kmeans(jnp.asarray(x), n_build_clusters, iters=8, seed=seed, metric=metric)
-    assign = np.asarray(km.assignments)
-    cand = _topk_neighbors_in_pools(
-        x, assign, np.asarray(km.centroids), n_candidates, link, metric
-    )
+    phase = lambda name: timed("index_build_phase", phase=name, n_rows=n)
+    with phase("graph_kmeans"):
+        km = kmeans(jnp.asarray(x), n_build_clusters, iters=8, seed=seed, metric=metric)
+        assign = np.asarray(km.assignments)
+    with phase("graph_pools"):
+        cand = _topk_neighbors_in_pools(
+            x, assign, np.asarray(km.centroids), n_candidates, link, metric
+        )
     xj = jnp.asarray(x)
     cand_j = jnp.asarray(cand)
-    for _ in range(nn_descent_rounds):
-        cand_j = _nn_descent_round(xj, cand_j, metric)
-    pruned = _robust_prune(xj, cand_j, m, prune_alpha, metric)
-    neighbors = _add_reverse_edges(np.asarray(pruned), m)
-    # medoid entry: point nearest to the global mean
-    mean = x.mean(0, keepdims=True)
-    entry = int(np.argmin(np.asarray(pairwise(jnp.asarray(mean), xj, metric))[0]))
-    neighbors = _repair_connectivity(neighbors, x, entry, metric)
+    with phase("graph_nn_descent"):
+        for _ in range(nn_descent_rounds):
+            cand_j = _nn_descent_round(xj, cand_j, metric)
+        cand_j.block_until_ready()
+    with phase("graph_prune"):
+        pruned = np.asarray(_robust_prune(xj, cand_j, m, prune_alpha, metric))
+    with phase("graph_reverse_edges"):
+        neighbors = _add_reverse_edges(pruned, m)
+    with phase("graph_repair"):
+        # medoid entry: point nearest to the global mean
+        mean = x.mean(0, keepdims=True)
+        entry = int(np.argmin(np.asarray(pairwise(jnp.asarray(mean), xj, metric))[0]))
+        neighbors = _repair_connectivity(neighbors, x, entry, metric)
     return GraphIndex(jnp.asarray(neighbors), jnp.asarray(np.int32(entry)))

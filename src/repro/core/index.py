@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.events import timed
+
 from .clustered_attrs import ClusteredAttrs, build_clustered_attrs
 from .graph_build import GraphIndex, build_graph
 from .kmeans import kmeans
@@ -143,14 +145,17 @@ def build_index(vectors: np.ndarray, attrs: np.ndarray, cfg: BuildConfig = Build
         metric=cfg.metric,
         seed=cfg.seed,
     )
-    km = kmeans(jnp.asarray(vectors), cfg.nlist, iters=cfg.kmeans_iters, seed=cfg.seed, metric=cfg.metric)
-    centroids = np.asarray(km.centroids)
-    assign = np.asarray(km.assignments)
-    medoids = cluster_medoids(vectors, assign, centroids, int(graph.entry), cfg.metric)
-    cattrs = build_clustered_attrs(attrs, assign, cfg.nlist)
-    astats = build_attr_stats(
-        attrs, assign, cfg.nlist, n_bins=cfg.hist_bins, n_cluster_bins=cfg.cluster_hist_bins
-    )
+    # each phase's wall time is an ``index_build_phase`` event (obs/events)
+    with timed("index_build_phase", phase="ivf_kmeans", n_rows=n):
+        km = kmeans(jnp.asarray(vectors), cfg.nlist, iters=cfg.kmeans_iters, seed=cfg.seed, metric=cfg.metric)
+        centroids = np.asarray(km.centroids)
+        assign = np.asarray(km.assignments)
+    with timed("index_build_phase", phase="runs_and_stats", n_rows=n):
+        medoids = cluster_medoids(vectors, assign, centroids, int(graph.entry), cfg.metric)
+        cattrs = build_clustered_attrs(attrs, assign, cfg.nlist)
+        astats = build_attr_stats(
+            attrs, assign, cfg.nlist, n_bins=cfg.hist_bins, n_cluster_bins=cfg.cluster_hist_bins
+        )
     # Sentinel padding rows. Attr sentinel = +inf fails every closed interval
     # whose hi is finite; predicates with hi = +inf (one-sided) are protected
     # by the validity masks in search, this is defence-in-depth.

@@ -74,7 +74,7 @@ def delta_topk(delta: DeltaView, queries, pred, k: int, metric: str, backend):
 
 def delta_topk_quantized(
     delta: DeltaView, queries, pred, k: int, metric: str, backend, quant,
-    luts=None, q_resids=None,
+    luts=None,
 ):
     """Quantized two-stage top-k over the delta segment.
 
@@ -85,7 +85,7 @@ def delta_topk_quantized(
     re-scores those exactly per ``quant.rerank`` ("full": the float32 delta
     rows, "decode": decoded codes, "none": trust the ADC order).
 
-    ``luts``/``q_resids`` optionally supply the per-query ADC tables —
+    ``luts`` optionally supplies the per-query ADC tables —
     the delta's codebooks are the base's frozen codebooks (see
     DeltaView.qvecs), so ``mutable_search`` builds the tables once and
     shares them with the base search; built here when omitted.
@@ -104,10 +104,7 @@ def delta_topk_quantized(
     mask = jnp.broadcast_to(delta.valid, (b, cap))
     if luts is None:
         luts = Q.build_luts(delta.qvecs, queries, metric)
-        q_resids = Q.residual_queries(delta.qvecs, queries)
-    dist, passing = backend.scan_scores_quantized(
-        delta, q_resids, luts, pred, ids, mask, metric
-    )
+    dist, passing = backend.scan_scores_quantized(delta, luts, pred, ids, mask, metric)
     dist = jnp.where(passing, dist, jnp.inf)
     n_adc = jnp.sum(mask, axis=1).astype(jnp.int32)
     k1 = min(k * quant.refine_factor, cap)

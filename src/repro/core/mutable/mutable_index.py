@@ -54,7 +54,6 @@ from ..quant.encode import (
     encode_rows,
     quant_mse,
     quantize_vectors,
-    residual_queries,
 )
 from ..quant.params import QuantConfig
 from .compact import fold_index, pad_index_rows
@@ -96,16 +95,14 @@ def mutable_search(
         # codebooks ARE the base's frozen codebooks (snapshot), so the same
         # (B, m, ks) tables score both tiers
         luts = build_luts(delta.qvecs, queries, pmr.metric)
-        q_resids = residual_queries(delta.qvecs, queries)
     else:
-        luts = q_resids = None
-    base = compass_search(index, queries, pred, pm, luts, q_resids)
+        luts = None
+    base = compass_search(index, queries, pred, pm, luts)
     bg = jnp.take(base_gids, jnp.clip(base.ids, 0, index.n_records), axis=0)
     bg = jnp.where(jnp.isfinite(base.dists), bg, jnp.int32(GID_SENTINEL))
     if quant_delta:
         dg, dd, n_adc, n_rr, n_pass = delta_topk_quantized(
-            delta, queries, pred, pmr.k, pmr.metric, backend, pm.quant,
-            luts, q_resids,
+            delta, queries, pred, pmr.k, pmr.metric, backend, pm.quant, luts,
         )
         stats = base.stats._replace(
             n_adc=base.stats.n_adc + n_adc,

@@ -171,7 +171,7 @@ def plan_query(index: CompassIndex, pred_lo, pred_hi, pm, quant: bool = False) -
 
 
 def plan_batch(
-    index: CompassIndex, queries, pred: P.Predicate, pm, backend, luts=None, q_resids=None
+    index: CompassIndex, queries, pred: P.Predicate, pm, backend, luts=None
 ) -> PlannedBatch:
     """Plan every query in the batch and pre-score the PREFILTER candidates.
 
@@ -181,8 +181,8 @@ def plan_batch(
     ``lax.cond`` on "any query chose PREFILTER" — a scalar predicate, so an
     all-COOPERATIVE batch pays only the probes, not the scan.
 
-    With ``luts``/``q_resids`` (the quantized tier: per-query (m, ks) ADC
-    tables + centered residual queries, built by the driver), the scan runs
+    With ``luts`` (the quantized tier: per-query (m, ks) ADC tables, built
+    by the driver), the scan runs
     over the PQ codes instead (``scan_scores_quantized`` — the pq_score
     kernel's (B, cap) grid) and the cost model prices rows at the ADC rate;
     the materialized candidates then carry ADC distances, which stage two's
@@ -203,7 +203,7 @@ def plan_batch(
     def do_scan(_):
         if quant:
             dist, passing = backend.scan_scores_quantized(
-                index, q_resids, luts, pred, plans.ids, scan_mask, pm.metric
+                index, luts, pred, plans.ids, scan_mask, pm.metric
             )
         else:
             dist, passing = backend.scan_scores(

@@ -13,9 +13,8 @@ Everything search needs at query time is a pure function of these arrays:
   * :func:`residual_queries` — center + zero-pad the query batch.
   * :func:`build_luts` — the per-query ``(m, ks)`` subspace distance
     tables (ADC's whole trick: a distance becomes ``m`` table lookups).
-    The l2 table math is shared with the Pallas kernel's in-kernel LUT
-    construction (``kernels.ref.subspace_lut``) so the ref and pallas
-    scoring paths agree bitwise.
+    The ref and pallas scoring paths both look up these tables, so they
+    agree bitwise.
   * :func:`decode` — codebook gather, for on-demand exact rerank without
     the full-precision rows.
 """
@@ -169,10 +168,8 @@ def build_luts(qv: QuantizedVectors, queries: jax.Array, metric: str) -> jax.Arr
     l2: ``lut[m, k] = ||q'_m - cb[m, k]||^2`` over centered-padded queries,
     summing to the exact decoded-row distance.  ip: ``lut[m, k] =
     -(q_m . cb[m, k])`` (raw encoding only; residual-ip is rejected at
-    train time because it would need a per-query bias).  Both metrics vmap
-    the same per-query expression the pq_score kernel builds in scratch
-    (``kernels.ref.adc_lut``), so the ref and pallas scoring paths agree
-    bitwise.
+    train time because it would need a per-query bias).  The ref and
+    pallas scoring paths both look up these tables, so they agree bitwise.
     """
     qr = residual_queries(qv, queries)  # (B, d_pad)
     return jax.vmap(lambda q: adc_lut(qv.codebooks, q, metric))(qr)

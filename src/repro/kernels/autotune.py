@@ -4,38 +4,41 @@ Block sizes (rows gathered per visit-step grid step, the ivf_score matmul
 tiles) trade VMEM residency against pipeline depth, and the right choice
 depends on the problem shape — d, V, m, B — not just the kernel.  Rather
 than hard-coding one default per kernel, each wrapper asks :func:`choose`
-for its block config.  Resolution order:
+for its block config.  Resolution order, each outcome counted in
+``compass_autotune_total{kernel,source}``:
 
-  1. **env pin** — ``REPRO_PALLAS_BLOCK_<KERNEL>`` (parsed by
-     ``kernels/interpret.py``), e.g. ``REPRO_PALLAS_BLOCK_VISIT_STEP="rb=4"``.
+  1. **pin** — ``REPRO_PALLAS_BLOCK_<KERNEL>`` (parsed by
+     ``kernels/interpret.py``), e.g. ``REPRO_PALLAS_BLOCK_VISIT_STEP="rb=8"``.
      A pin wins over everything and is never measured against.
-  2. **measured table** — an in-process ``{(kernel, shape_key): config}``
-     cache.  On first sight of a shape (and only when measurement is
-     enabled — see ``interpret.autotune_measurement_enabled``) every
-     candidate is timed on throwaway arrays of the real shape and the
-     fastest wins; the result is cached so each shape pays the probe once
-     per process.
-  3. **built-in default** — ``candidates[0]``, used when measurement is
-     off (the CPU-interpret path: interpret-mode timings would tune for
-     the interpreter, not the hardware).
+  2. **table** — an in-process ``{(kernel, shape_key): config}`` cache of
+     *measured* winners.
+  3. **measured** — on first sight of a shape, when the wrapper can supply
+     a measure function and measurement is enabled (see
+     ``interpret.autotune_measurement_enabled``), every candidate runs on
+     the wrapper's own concrete arguments and the fastest wins.  A
+     candidate the compiler refuses is skipped and counted as
+     ``refused``; when every candidate is refused, :func:`choose` raises.
+  4. **default** — ``candidates[0]``.  Used when measurement is off (the
+     CPU-interpret path: interpret-mode timings would tune for the
+     interpreter, not the hardware) and whenever the wrapper is being
+     *traced*: a kernel reached inside an outer jit (the engine hot path)
+     only has tracers, and timing a tracer times tracing, not the kernel.
+     A default is not stored in the table, so a later concrete call can
+     still measure that shape.
 
-Timing happens eagerly on concrete dummy arrays, so it is legal even when
-``choose`` is reached at trace time inside an outer jit (the engine hot
-path) — only the *chosen ints* flow into the traced program.  Block
-choice never affects results: every candidate computes the same values
-(tests assert bitwise equality across block sizes), so a cold cache, a
-pin, or a mis-measured table can cost speed but never correctness.
+Block choice never affects results: every candidate computes the same
+values (tests assert bitwise equality across block sizes), so a default,
+a pin, or a mis-measured table can cost speed but never correctness.
 
-The table format (what BENCH_kernels.json snapshots and DESIGN.md §Perf
-documents): ``key = (kernel, shape_key)`` where ``shape_key`` is the
-wrapper-chosen tuple of shape-determining ints/strs (e.g. visit_step uses
-``(d, a, t, v, metric, has_live, interpret)``), ``value`` the config dict
-(e.g. ``{"rb": 4}``).  ``snapshot()`` exports it for bench provenance.
+``decisions()`` reports the last resolution per shape with its source —
+what a run actually used (bench provenance, ``chip_smoke.py``).
 """
 from __future__ import annotations
 
 import time
 from typing import Any, Callable, Sequence
+
+import jax
 
 from .interpret import autotune_measurement_enabled, block_override
 
@@ -44,25 +47,43 @@ Config = dict[str, int]
 _TABLE: dict[tuple[str, tuple], Config] = {}
 #: shapes measured this process (bookkeeping, asserted on by tests)
 _N_MEASURED: dict[tuple[str, tuple], int] = {}
+#: last resolution per shape: (config, source)
+_DECISIONS: dict[tuple[str, tuple], tuple[Config, str]] = {}
 
 
 def clear() -> None:
-    """Drop the measured table (tests)."""
+    """Drop the measured table and the decision log (tests)."""
     _TABLE.clear()
     _N_MEASURED.clear()
+    _DECISIONS.clear()
 
 
-def snapshot() -> dict[str, Config]:
-    """The measured table as a JSON-able dict (bench provenance)."""
-    return {f"{k[0]}:{k[1]}": dict(v) for k, v in sorted(_TABLE.items(), key=str)}
+def decisions() -> dict[str, dict]:
+    """Every shape resolved this process: its config and where it came
+    from (``pin`` / ``table`` / ``measured`` / ``default``)."""
+    return {
+        f"{k[0]}:{k[1]}": {"config": dict(cfg), "source": src}
+        for k, (cfg, src) in sorted(_DECISIONS.items(), key=str)
+    }
 
 
 def _measure(fn: Callable[[Config], Any], cand: Config, reps: int = 3) -> float:
-    fn(cand)  # warmup: compile + first run
+    """Best-of-``reps`` wall time of ``fn(cand)``, waiting for the device.
+
+    Raises if ``fn`` returns tracers: ``block_until_ready`` returns a
+    tracer without waiting, so the clock would time tracing."""
+
+    def run():
+        out = fn(cand)
+        if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(out)):
+            raise RuntimeError("autotune measure function returned tracers")
+        jax.block_until_ready(out)
+
+    run()  # warmup: compile + first run
     best = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
-        fn(cand)
+        run()
         best = min(best, time.perf_counter() - t0)
     return best
 
@@ -75,41 +96,38 @@ def choose(
 ) -> Config:
     """Resolve the block config for one kernel launch shape.
 
-    ``measure_fn`` runs one candidate end-to-end on dummy data of the real
-    shape and blocks until done (the wrapper supplies it); candidates that
-    raise are skipped.  ``candidates[0]`` is the built-in default.
-
-    Every resolution bumps ``compass_autotune_total{kernel,source}`` with
-    the outcome (``pin``/``table``/``measured``/``default`` — see
-    obs/profiling.py), so the decision that produced a given block config
-    is visible at runtime without re-deriving the resolution order.
+    ``measure_fn`` runs one candidate on the wrapper's concrete arguments
+    and returns its output; wrappers pass None while being traced.
+    ``candidates[0]`` is the built-in default.
     """
     from repro.obs import profiling as prof
 
+    key = (kernel, tuple(shape_key))
     pinned = block_override(kernel)
     if pinned:
-        cfg = dict(candidates[0])
+        cfg, source = dict(candidates[0]), "pin"
         cfg.update(pinned)
-        prof.count_autotune(kernel, "pin")
-        return cfg
-    key = (kernel, tuple(shape_key))
-    hit = _TABLE.get(key)
-    if hit is not None:
-        prof.count_autotune(kernel, "table")
-        return dict(hit)
-    cfg = dict(candidates[0])
-    if measure_fn is not None and autotune_measurement_enabled():
+    elif key in _TABLE:
+        cfg, source = dict(_TABLE[key]), "table"
+    elif measure_fn is not None and autotune_measurement_enabled():
         _N_MEASURED[key] = _N_MEASURED.get(key, 0) + 1
-        best_t = float("inf")
+        timed, refused = [], []
         for cand in candidates:
             try:
-                t = _measure(measure_fn, dict(cand))
-            except Exception:  # an illegal tiling for this shape: skip it
-                continue
-            if t < best_t:
-                best_t, cfg = t, dict(cand)
-        prof.count_autotune(kernel, "measured")
+                timed.append((_measure(measure_fn, dict(cand)), dict(cand)))
+            except Exception as e:  # the compiler refused this tiling
+                refused.append(f"{cand}: {type(e).__name__}: {e}")
+                prof.count_autotune(kernel, "refused")
+        if not timed:
+            raise RuntimeError(
+                f"{kernel}: every block candidate was refused for shape "
+                f"{shape_key}:\n" + "\n".join(refused)
+            )
+        cfg = min(timed, key=lambda tc: tc[0])[1]
+        source = "measured"
+        _TABLE[key] = dict(cfg)
     else:
-        prof.count_autotune(kernel, "default")
-    _TABLE[key] = dict(cfg)
+        cfg, source = dict(candidates[0]), "default"
+    prof.count_autotune(kernel, source)
+    _DECISIONS[key] = (dict(cfg), source)
     return cfg

@@ -1,48 +1,32 @@
 """Fused gather + distance + predicate Pallas TPU kernel — the Compass
-query hot-spot (Algorithm 4's VISIT over a batch of candidate ids).
+query hot-spot (Algorithm 4's VISIT over a batch of candidate ids), and
+its batched form, the planner's PREFILTER run scan.
 
-TPU design (vs. the paper's CPU SIMD loop):
-  * candidate ids are *scalar-prefetched* (PrefetchScalarGridSpec) so the
-    BlockSpec index_map can steer per-step DMA: grid step i pulls row
-    idx[i] of `vectors`/`attrs` HBM->VMEM while step i-1 computes — the
-    canonical TPU row-gather pattern (double-buffered by the pipeline).
-  * distance (squared L2 or negated inner product — static ``metric``,
-    shared expression ``ref.row_distance``) reduces on the VPU over the
-    (1, d) row against the VMEM-resident query.
-  * the DNF interval predicate evaluates on the gathered (1, A) attr row
-    against (T, A) bounds; the visit mask fuses in by pointing masked
-    steps at the sentinel row N, yielding +inf distance and pass=false —
-    exactly the reference semantics in kernels/ref.py.
+Both entry points run the shared row-gather pipeline
+(kernels/row_gather.py): candidate ids are scalar-prefetched, the rows of
+``vectors`` are DMA'd from HBM into a double-buffered VMEM scratch ``RB``
+rows per grid step (gathered by XLA where ``d`` is not a multiple of
+128), the distance (squared L2 or negated inner product —
+static ``metric``, shared expression ``ref.row_distance``) reduces on the
+VPU against the VMEM-resident query, and the DNF interval predicate
+evaluates on the candidates' attribute rows (gathered by XLA, ``RB`` rows
+per block).  Masked slots point at the sentinel row N, yielding +inf
+distance and pass=false — exactly the reference semantics in
+kernels/ref.py.
 
-VMEM working set per step: d + A + 2*T*A + O(1) floats — tiny; the win is
-fusing three HBM round-trips (gather, distance, filter) into one.
+VMEM working set per step: 2·RB·(d + A) + d + 2·T·A floats — e.g. RB=8,
+d=128, A=4: ~8.5 KB.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .interpret import default_interpret
-from .ref import row_distance
+from .row_gather import gather_score
 
-
-def _kernel(idx_ref, vec_ref, attr_ref, q_ref, lo_ref, hi_ref, dist_ref, pass_ref, *, n, metric):
-    i = pl.program_id(0)
-    valid = idx_ref[i] < n  # sentinel row == masked-out visit
-    vec = vec_ref[0, :]  # (d,) gathered row (index-mapped via idx_ref)
-    q = q_ref[0, :]
-    dist = row_distance(vec, q, metric)
-    attrs = attr_ref[0, :]  # (A,)
-    lo = lo_ref[...]  # (T, A)
-    hi = hi_ref[...]
-    term_ok = jnp.all((attrs[None, :] >= lo) & (attrs[None, :] <= hi), axis=1)
-    passed = jnp.any(term_ok)
-    dist_ref[0] = jnp.where(valid, dist, jnp.inf)
-    pass_ref[0] = jnp.where(valid, passed, False).astype(jnp.int32)
+#: rows gathered per grid step by the scan kernels
+ROWS_PER_STEP = 8
 
 
 def filter_distance(
@@ -57,71 +41,17 @@ def filter_distance(
     metric: str = "l2",
     interpret: bool | None = None,
 ):
-    """Returns (dists (V,) f32, +inf where masked; passed (V,) bool).
+    """Returns (dists (V,) f32, +inf where masked; passed (V,) bool) — the
+    one-lane case of :func:`filter_distance_batch`.
 
     ``metric``: "l2" (squared L2) or "ip" (negated inner product).  The
-    interpret default comes from kernels/interpret.py — see its docstring
-    for the env overrides and the trace-time-baking caveat.
+    interpret default comes from kernels/interpret.py.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    return _filter_distance(vectors, attrs, idx, mask, q, lo, hi,
-                            metric=metric, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "interpret"))
-def _filter_distance(vectors, attrs, idx, mask, q, lo, hi, *, metric: str, interpret: bool):
-    v = idx.shape[0]
-    n = vectors.shape[0] - 1
-    d = vectors.shape[1]
-    a = attrs.shape[1]
-    t = lo.shape[0]
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, n), n).astype(jnp.int32)
-    dists, passed = pl.pallas_call(
-        functools.partial(_kernel, n=n, metric=metric),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(v,),
-            in_specs=[
-                pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0)),
-                pl.BlockSpec((1, a), lambda i, idx_ref: (idx_ref[i], 0)),
-                pl.BlockSpec((1, d), lambda i, idx_ref: (0, 0)),
-                pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-                pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1,), lambda i, idx_ref: (i,)),
-                pl.BlockSpec((1,), lambda i, idx_ref: (i,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((v,), jnp.float32),
-            jax.ShapeDtypeStruct((v,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(safe_idx, vectors, attrs, q[None, :], lo, hi)
-    return dists, passed.astype(bool)
-
-
-# ---------------------------------------------------------------------------
-# Batched run-scan entry point — the planner's PREFILTER hot spot.
-# ---------------------------------------------------------------------------
-
-
-def _kernel_batch(idx_ref, vec_ref, attr_ref, q_ref, lo_ref, hi_ref, dist_ref, pass_ref, *, n, metric):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    valid = idx_ref[b, i] < n  # sentinel row == masked-out slot
-    vec = vec_ref[0, :]  # (d,) gathered row (index-mapped via idx_ref)
-    q = q_ref[0, :]  # (d,) this lane's query
-    dist = row_distance(vec, q, metric)
-    attrs = attr_ref[0, :]  # (A,)
-    lo = lo_ref[0]  # (T, A) this lane's DNF bounds
-    hi = hi_ref[0]
-    term_ok = jnp.all((attrs[None, :] >= lo) & (attrs[None, :] <= hi), axis=1)
-    passed = jnp.any(term_ok)
-    dist_ref[0, 0] = jnp.where(valid, dist, jnp.inf)
-    pass_ref[0, 0] = jnp.where(valid, passed, False).astype(jnp.int32)
+    dists, passed = filter_distance_batch(
+        vectors, attrs, idx[None], mask[None], q[None], lo[None], hi[None],
+        metric=metric, interpret=interpret,
+    )
+    return dists[0], passed[0]
 
 
 def filter_distance_batch(
@@ -136,51 +66,18 @@ def filter_distance_batch(
     metric: str = "l2",
     interpret: bool | None = None,
 ):
-    """Batched variant of :func:`filter_distance` for the planner's
-    PREFILTER run scan: one blocked ``pallas_call`` over grid (B, V) for the
-    whole micro-batch instead of a vmapped per-query call.  The inner grid
-    dimension keeps the scalar-prefetched per-step row gather; the per-lane
-    query / bounds blocks only re-DMA when the outer (lane) index advances.
+    """Batched :func:`filter_distance` for the planner's PREFILTER run
+    scan: one ``pallas_call`` over grid (B, V / RB) for the whole
+    micro-batch instead of a vmapped per-query call; the per-lane query
+    and bounds blocks re-DMA only when the lane changes.
 
     Returns (dists (B, V) f32, +inf where masked; passed (B, V) bool).
     """
     if interpret is None:
         interpret = default_interpret()
-    return _filter_distance_batch(
-        vectors, attrs, idx, mask, queries, lo, hi, metric=metric, interpret=interpret
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "interpret"))
-def _filter_distance_batch(vectors, attrs, idx, mask, queries, lo, hi, *,
-                           metric: str, interpret: bool):
-    b, v = idx.shape
     n = vectors.shape[0] - 1
-    d = vectors.shape[1]
-    a = attrs.shape[1]
-    t = lo.shape[1]
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, n), n).astype(jnp.int32)
-    dists, passed = pl.pallas_call(
-        functools.partial(_kernel_batch, n=n, metric=metric),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, v),
-            in_specs=[
-                pl.BlockSpec((1, d), lambda bi, i, idx_ref: (idx_ref[bi, i], 0)),
-                pl.BlockSpec((1, a), lambda bi, i, idx_ref: (idx_ref[bi, i], 0)),
-                pl.BlockSpec((1, d), lambda bi, i, idx_ref: (bi, 0)),
-                pl.BlockSpec((1, t, a), lambda bi, i, idx_ref: (bi, 0, 0)),
-                pl.BlockSpec((1, t, a), lambda bi, i, idx_ref: (bi, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1), lambda bi, i, idx_ref: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, idx_ref: (bi, i)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, v), jnp.float32),
-            jax.ShapeDtypeStruct((b, v), jnp.int32),
-        ],
-        interpret=interpret,
-    )(safe_idx, vectors, attrs, queries, lo, hi)
-    return dists, passed.astype(bool)
+    safe = jnp.where(mask, jnp.clip(idx, 0, n), n)
+    return gather_score(
+        safe, vectors, attrs, queries[:, None, :], lo, hi,
+        score=metric, emit="pass", rb=ROWS_PER_STEP, interpret=interpret,
+    )

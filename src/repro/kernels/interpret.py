@@ -1,11 +1,10 @@
-"""Single source of truth for the Pallas kernel env knobs.
+"""Single source of truth for the Pallas kernel mode and env knobs.
 
-Interpret mode is platform auto-detected: native TPU lowers to Mosaic,
-everywhere else (CPU containers included) the Pallas interpreter executes
-the kernel body for correctness.  Env overrides, checked in order:
-
-  REPRO_PALLAS_COMPILE=1    force native lowering
-  REPRO_PALLAS_INTERPRET=1  force the interpreter
+Interpret mode follows the platform and nothing else: on a TPU every
+kernel lowers natively to Mosaic; everywhere else (CPU containers
+included) the Pallas interpreter executes the kernel body for
+correctness.  There is no override — a TPU run can never silently fall
+into the interpreter.
 
 Block-size pins (consumed by kernels/autotune.py, one variable per
 kernel, comma-separated ``field=int`` pairs):
@@ -19,7 +18,7 @@ measurement itself is gated by REPRO_PALLAS_AUTOTUNE=1/0 (default: only
 measure when the kernels lower natively — interpret-mode timings would
 tune for the interpreter, not the hardware).
 
-All of these are read when the wrapper runs, which for the engine hot
+The pins are read when the wrapper runs, which for the engine hot
 path is at *trace* time inside the outer ``compass_search`` jit — the
 result is baked into the cached executable and later in-process env
 changes are ignored for already-traced shapes.  Set overrides before the
@@ -33,10 +32,6 @@ import jax
 
 
 def default_interpret() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
-    if os.environ.get("REPRO_PALLAS_INTERPRET", "0") == "1":
-        return True
     return jax.default_backend() != "tpu"
 
 

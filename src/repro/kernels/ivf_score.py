@@ -15,7 +15,8 @@ d-block.  The accumulator lives in the output block across the d-grid
 Block sizes (``bb``/``bc``/``bd``) resolve through ``kernels/autotune.py``
 when not passed explicitly: pin with
 ``REPRO_PALLAS_BLOCK_IVF_SCORE="bb=8,bc=128,bd=128"``, else the measured
-per-shape table, else the 8/128/128 default.  Tile choice only re-blocks
+per-shape table (measured only on concrete arguments, never while
+traced), else the 8/128/128 default.  Tile choice only re-blocks
 the same f32 accumulation order per (query, centroid) pair along d, so
 results are tile-independent up to the documented MXU-vs-ref ULP caveat
 (engine/backend.py).
@@ -61,17 +62,16 @@ def _kernel(q_ref, c_ref, out_ref, *, nd_blocks, metric):
     out_ref[...] = acc
 
 
-def _tuned_blocks(b, c, d, dtype, metric, interpret) -> dict[str, int]:
-    def measure(cfg):
-        out = _ivf_score(
-            jnp.zeros((b, d), dtype), jnp.zeros((c, d), dtype),
-            metric=metric, interpret=interpret, **cfg,
-        )
-        jax.block_until_ready(out)
+def _tuned_blocks(queries, centroids, metric, interpret) -> dict[str, int]:
+    concrete = not any(isinstance(x, jax.core.Tracer) for x in (queries, centroids))
 
+    def measure(cfg):
+        return _ivf_score(queries, centroids, metric=metric, interpret=interpret, **cfg)
+
+    b, d = queries.shape
     return autotune.choose(
-        "ivf_score", (b, c, d, str(dtype), metric, interpret),
-        _BLOCK_CANDIDATES, measure,
+        "ivf_score", (b, centroids.shape[0], d, str(queries.dtype), metric, interpret),
+        _BLOCK_CANDIDATES, measure if concrete else None,
     )
 
 
@@ -88,16 +88,12 @@ def ivf_score(
     """Centroid distance scores (B, C): squared L2 or negated inner product.
 
     Unset block sizes resolve through the autotuner; explicit values always
-    win.  The interpret default comes from kernels/interpret.py — see its
-    docstring for the env overrides and the trace-time-baking caveat.
+    win.  The interpret default comes from kernels/interpret.py.
     """
     if interpret is None:
         interpret = default_interpret()
     if bb is None or bc is None or bd is None:
-        tuned = _tuned_blocks(
-            queries.shape[0], centroids.shape[0], queries.shape[1],
-            queries.dtype, metric, interpret,
-        )
+        tuned = _tuned_blocks(queries, centroids, metric, interpret)
         bb, bc, bd = bb or tuned["bb"], bc or tuned["bc"], bd or tuned["bd"]
     return _ivf_score(queries, centroids, metric=metric, bb=bb, bc=bc, bd=bd,
                       interpret=interpret)

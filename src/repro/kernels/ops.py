@@ -1,14 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Interpret mode is platform auto-detected (see ``kernels/interpret.py``:
+Interpret mode follows the platform (see ``kernels/interpret.py``:
 native TPU lowers to Mosaic, everywhere else the Pallas interpreter
 executes the kernel body for correctness, so the engine's ``"pallas"``
-backend is testable on CPU; ``REPRO_PALLAS_COMPILE=1`` /
-``REPRO_PALLAS_INTERPRET=1`` force-override, and
-``REPRO_PALLAS_BLOCK_*`` pins kernel block sizes past the autotuner).
-The detection runs per *trace*, not per call: inside an outer jit (e.g.
-``compass_search``) the value is baked into the cached executable, so set
-the env overrides before the first traced call.  ``use_pallas=False``
+backend is testable on CPU; ``REPRO_PALLAS_BLOCK_*`` pins kernel block
+sizes past the autotuner).  ``use_pallas=False``
 falls back to the jnp oracle — search code paths stay identical either
 way.
 
@@ -20,8 +16,9 @@ bumps ``compass_kernel_fallback_total{kernel,reason}``.  Both record at
 wrapper-call time — inside a jit that is *trace time*, once per compile,
 the same semantics as the ``visit_step.TRACE_COUNT`` CI tripwire.
 
-Scoring kernels take ``metric`` ("l2" squared L2 / "ip" negated inner
-product); cosine runs as ip over normalized rows and never reaches this
+Full-precision scoring kernels take ``metric`` ("l2" squared L2 / "ip"
+negated inner product; the ADC kernels take tables that already carry
+it); cosine runs as ip over normalized rows and never reaches this
 layer (the engine rewrites it — see core/engine/driver.py).
 """
 from __future__ import annotations
@@ -76,29 +73,21 @@ def visit_step(vectors, attrs, live, idx, mask, q, lo, hi, *,
                                   metric=metric, **kw)
 
 
-def pq_score(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, *,
-             metric: str = "l2", use_pallas: bool = True):
+def pq_score(codes, attrs, idx, mask, lut, lo, hi, *, use_pallas: bool = True):
+    """ADC scoring of one query's visit list against its (m, ks) table."""
     if not use_pallas:
         prof.count_fallback("pq_score", "use_pallas=False")
-        return ref.pq_score_ref(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, metric)
+        return ref.pq_score_ref(codes, attrs, idx, mask, lut, lo, hi)
     with prof.kernel_scope("pq_score"):
-        return _pq_score_kernel(codes, attrs, idx, mask, q_resid, codebooks, lo, hi,
-                                metric=metric)
+        return _pq_score_kernel(codes, attrs, idx, mask, lut, lo, hi)
 
 
-def pq_score_batch(
-    codes, attrs, idx, mask, q_resid, codebooks, lo, hi, *,
-    metric: str = "l2", use_pallas: bool = True
-):
+def pq_score_batch(codes, attrs, idx, mask, luts, lo, hi, *, use_pallas: bool = True):
     if not use_pallas:
         prof.count_fallback("pq_score", "use_pallas=False")
-        return ref.pq_score_batch_ref(
-            codes, attrs, idx, mask, q_resid, codebooks, lo, hi, metric
-        )
+        return ref.pq_score_batch_ref(codes, attrs, idx, mask, luts, lo, hi)
     with prof.kernel_scope("pq_score"):
-        return _pq_score_batch_kernel(
-            codes, attrs, idx, mask, q_resid, codebooks, lo, hi, metric=metric
-        )
+        return _pq_score_batch_kernel(codes, attrs, idx, mask, luts, lo, hi)
 
 
 def ivf_score(queries, centroids, *, metric: str = "l2", use_pallas: bool = True, **kw):

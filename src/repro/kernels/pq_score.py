@@ -3,74 +3,36 @@ hot-spot (Algorithm 4's VISIT, and the PREFILTER / delta brute scans, over
 uint8 PQ codes instead of float32 rows).
 
 Asymmetric distance computation turns one d-dim distance into ``m`` table
-lookups.  The kernel fuses the whole per-query pipeline:
+lookups.  The per-query (m, ks) table is built once per query outside the
+kernel (``quant/encode.build_luts`` over ``ref.adc_lut``, which carries
+the metric: squared-L2 tables, or negated inner product over raw codes —
+see quant/params.py), so the kernel and the jnp oracle look up the very
+same table.  The kernel runs the shared row-gather pipeline
+(kernels/row_gather.py):
 
-  * **LUT construction** — at each lane's first grid step the (m, ks)
-    subspace distance table is built in VMEM scratch from the centered
-    query block and the VMEM-resident codebooks (``ref.subspace_lut`` — the
-    same expression the jnp path vmaps, so parity is bitwise); it then
-    persists in scratch across that lane's code gathers.
-  * **blocked code gather** — candidate ids are scalar-prefetched
-    (PrefetchScalarGridSpec) so the BlockSpec index_map steers per-step
-    DMA of the (1, m) uint8 code row, double-buffered by the pipeline —
-    m bytes per candidate instead of 4·d.
-  * **table lookups on the VPU** — the dynamic per-code gather is lowered
-    as a one-hot select over the (m, ks) LUT (TPU vector units have no
-    arbitrary-index VMEM gather; ks <= 256 keeps the select tiny).  Adding
-    the masked-out zeros is exact in f32, so the reduction is bitwise
-    identical to the oracle's take-then-sum.
-  * **predicate masking** — the gathered (1, A) attr row evaluates the DNF
-    bounds exactly as kernels/filter_distance.py; masked steps point at
-    the sentinel row N and yield +inf / false.
+  * **blocked code rows** — the candidates' uint8 code rows (m bytes
+    each instead of 4·d) are gathered by XLA and enter as (RB, m) row
+    blocks: Mosaic refuses a one-row DMA slice of a narrow uint8 table.
+  * **table lookups on the VPU** — the dynamic per-code gather is a
+    one-hot select over each (1, ks) table row (TPU vector units have no
+    arbitrary-index VMEM gather).  Adding the masked-out zeros is exact in
+    f32 and the m partial values fold through ``ref.chain_sum_m``, so the
+    result is bitwise identical to the oracle's take-then-sum.
+  * **predicate masking** — the candidates' attribute rows evaluate the
+    DNF bounds exactly as kernels/filter_distance.py; masked slots point
+    at the sentinel row N and yield +inf / false.
 
-VMEM working set per step: m·ks (LUT) + m·ks·dsub (codebooks) + d + A +
-2·T·A float32s — e.g. m=16, ks=256, d=128: 16 KB LUT + 131 KB codebooks
-≈ 148 KB, far under the ~16 MB budget.
-Tables are squared-L2 or negated inner product (static ``metric``; ip
-codes are raw, not residual-centered — see quant/params.py).
+VMEM working set per step: m·ks (table) + 2·RB·(m + A) + 2·T·A — e.g.
+m=16, ks=256: 16 KB of table.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from .filter_distance import ROWS_PER_STEP
 from .interpret import default_interpret
-from .ref import adc_lut, chain_sum_m
-
-
-def _lookup_sum(codes, lut_ref, ks: int):
-    """dist = sum_m lut[m, codes[m]] via one-hot select (VPU-friendly: TPU
-    vector units have no arbitrary-index VMEM gather; adding the masked
-    zeros is exact in f32).  The m partial values fold through the same
-    sequential chain as the oracle (ref.chain_sum_m) for bitwise parity."""
-    m = codes.shape[0]
-    onehot = codes[:, None] == jax.lax.broadcasted_iota(jnp.int32, (m, ks), 1)
-    row = jnp.sum(jnp.where(onehot, lut_ref[...], 0.0), axis=1)  # (m,)
-    return chain_sum_m([row[mi] for mi in range(m)])
-
-
-def _kernel(idx_ref, codes_ref, attr_ref, q_ref, cb_ref, lo_ref, hi_ref,
-            dist_ref, pass_ref, lut_ref, *, n, ks, metric):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _build_lut():
-        lut_ref[...] = adc_lut(cb_ref[...], q_ref[0, :], metric)
-
-    valid = idx_ref[i] < n  # sentinel row == masked-out visit
-    codes = codes_ref[0, :].astype(jnp.int32)  # (m,) gathered code row
-    dist = _lookup_sum(codes, lut_ref, ks)
-    attrs = attr_ref[0, :]  # (A,)
-    lo = lo_ref[...]  # (T, A)
-    hi = hi_ref[...]
-    term_ok = jnp.all((attrs[None, :] >= lo) & (attrs[None, :] <= hi), axis=1)
-    passed = jnp.any(term_ok)
-    dist_ref[0] = jnp.where(valid, dist, jnp.inf)
-    pass_ref[0] = jnp.where(valid, passed, False).astype(jnp.int32)
+from .row_gather import gather_score
 
 
 def pq_score(
@@ -78,90 +40,19 @@ def pq_score(
     attrs: jax.Array,  # (N + 1, A)
     idx: jax.Array,  # (V,) int32 candidate ids (may repeat / sentinel)
     mask: jax.Array,  # (V,) bool visit mask
-    q_resid: jax.Array,  # (d_pad,) centered zero-padded query
-    codebooks: jax.Array,  # (m, ks, dsub)
+    lut: jax.Array,  # (m, ks) the query's ADC table
     lo: jax.Array,  # (T, A)
     hi: jax.Array,  # (T, A)
     *,
-    metric: str = "l2",
     interpret: bool | None = None,
 ):
-    """Returns (dists (V,) f32, +inf where masked; passed (V,) bool).
-
-    ``metric`` selects the in-scratch LUT expression (ref.adc_lut): "l2"
-    squared-L2 tables, "ip" negated-inner-product tables over raw (non-
-    residual) codes.  The interpret default comes from
-    kernels/interpret.py — see its docstring for the env overrides and the
-    trace-time-baking caveat.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    return _pq_score(codes, attrs, idx, mask, q_resid, codebooks, lo, hi,
-                     metric=metric, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "interpret"))
-def _pq_score(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, *,
-              metric: str, interpret: bool):
-    v = idx.shape[0]
-    n = codes.shape[0] - 1
-    m, ks, dsub = codebooks.shape
-    dp = q_resid.shape[0]
-    a = attrs.shape[1]
-    t = lo.shape[0]
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, n), n).astype(jnp.int32)
-    dists, passed = pl.pallas_call(
-        functools.partial(_kernel, n=n, ks=ks, metric=metric),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(v,),
-            in_specs=[
-                pl.BlockSpec((1, m), lambda i, idx_ref: (idx_ref[i], 0)),
-                pl.BlockSpec((1, a), lambda i, idx_ref: (idx_ref[i], 0)),
-                pl.BlockSpec((1, dp), lambda i, idx_ref: (0, 0)),
-                pl.BlockSpec((m, ks, dsub), lambda i, idx_ref: (0, 0, 0)),
-                pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-                pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1,), lambda i, idx_ref: (i,)),
-                pl.BlockSpec((1,), lambda i, idx_ref: (i,)),
-            ],
-            scratch_shapes=[pltpu.VMEM((m, ks), jnp.float32)],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((v,), jnp.float32),
-            jax.ShapeDtypeStruct((v,), jnp.int32),
-        ],
+    """Returns (dists (V,) f32, +inf where masked; passed (V,) bool) — the
+    one-lane case of :func:`pq_score_batch`."""
+    dists, passed = pq_score_batch(
+        codes, attrs, idx[None], mask[None], lut[None], lo[None], hi[None],
         interpret=interpret,
-    )(safe_idx, codes, attrs, q_resid[None, :], codebooks, lo, hi)
-    return dists, passed.astype(bool)
-
-
-# ---------------------------------------------------------------------------
-# Batched scan entry point — PREFILTER / delta brute scans over codes.
-# ---------------------------------------------------------------------------
-
-
-def _kernel_batch(idx_ref, codes_ref, attr_ref, q_ref, cb_ref, lo_ref, hi_ref,
-                  dist_ref, pass_ref, lut_ref, *, n, ks, metric):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)  # lane boundary: rebuild this lane's LUT once
-    def _build_lut():
-        lut_ref[...] = adc_lut(cb_ref[...], q_ref[0, :], metric)
-
-    valid = idx_ref[b, i] < n
-    codes = codes_ref[0, :].astype(jnp.int32)
-    dist = _lookup_sum(codes, lut_ref, ks)
-    attrs = attr_ref[0, :]
-    lo = lo_ref[0]  # (T, A) this lane's DNF bounds
-    hi = hi_ref[0]
-    term_ok = jnp.all((attrs[None, :] >= lo) & (attrs[None, :] <= hi), axis=1)
-    passed = jnp.any(term_ok)
-    dist_ref[0, 0] = jnp.where(valid, dist, jnp.inf)
-    pass_ref[0, 0] = jnp.where(valid, passed, False).astype(jnp.int32)
+    )
+    return dists[0], passed[0]
 
 
 def pq_score_batch(
@@ -169,59 +60,22 @@ def pq_score_batch(
     attrs: jax.Array,  # (N + 1, A)
     idx: jax.Array,  # (B, V) int32 candidate ids
     mask: jax.Array,  # (B, V) bool valid-slot mask
-    q_resid: jax.Array,  # (B, d_pad) centered zero-padded queries
-    codebooks: jax.Array,  # (m, ks, dsub)
+    luts: jax.Array,  # (B, m, ks) per-lane ADC tables
     lo: jax.Array,  # (B, T, A) per-lane DNF bounds
     hi: jax.Array,  # (B, T, A)
     *,
-    metric: str = "l2",
     interpret: bool | None = None,
 ):
-    """Batched :func:`pq_score`: one blocked grid-(B, V) call for a whole
-    micro-batch; the per-lane LUT is rebuilt in scratch at each lane
-    boundary and reused across that lane's V code gathers.
+    """Batched :func:`pq_score`: one grid-(B, V / RB) call for a whole
+    micro-batch; each lane's table block is fetched once per lane.
 
     Returns (dists (B, V) f32, +inf where masked; passed (B, V) bool).
     """
     if interpret is None:
         interpret = default_interpret()
-    return _pq_score_batch(codes, attrs, idx, mask, q_resid, codebooks, lo, hi,
-                           metric=metric, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "interpret"))
-def _pq_score_batch(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, *,
-                    metric: str, interpret: bool):
-    b, v = idx.shape
     n = codes.shape[0] - 1
-    m, ks, dsub = codebooks.shape
-    dp = q_resid.shape[1]
-    a = attrs.shape[1]
-    t = lo.shape[1]
-    safe_idx = jnp.where(mask, jnp.clip(idx, 0, n), n).astype(jnp.int32)
-    dists, passed = pl.pallas_call(
-        functools.partial(_kernel_batch, n=n, ks=ks, metric=metric),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, v),
-            in_specs=[
-                pl.BlockSpec((1, m), lambda bi, i, idx_ref: (idx_ref[bi, i], 0)),
-                pl.BlockSpec((1, a), lambda bi, i, idx_ref: (idx_ref[bi, i], 0)),
-                pl.BlockSpec((1, dp), lambda bi, i, idx_ref: (bi, 0)),
-                pl.BlockSpec((m, ks, dsub), lambda bi, i, idx_ref: (0, 0, 0)),
-                pl.BlockSpec((1, t, a), lambda bi, i, idx_ref: (bi, 0, 0)),
-                pl.BlockSpec((1, t, a), lambda bi, i, idx_ref: (bi, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1), lambda bi, i, idx_ref: (bi, i)),
-                pl.BlockSpec((1, 1), lambda bi, i, idx_ref: (bi, i)),
-            ],
-            scratch_shapes=[pltpu.VMEM((m, ks), jnp.float32)],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, v), jnp.float32),
-            jax.ShapeDtypeStruct((b, v), jnp.int32),
-        ],
-        interpret=interpret,
-    )(safe_idx, codes, attrs, q_resid, codebooks, lo, hi)
-    return dists, passed.astype(bool)
+    safe = jnp.where(mask, jnp.clip(idx, 0, n), n)
+    return gather_score(
+        safe, codes, attrs, luts, lo, hi,
+        score="adc", emit="pass", rb=ROWS_PER_STEP, interpret=interpret,
+    )

@@ -7,18 +7,20 @@ import jax
 import jax.numpy as jnp
 
 
-def row_distance(vec, q, metric):
+def row_distance(vec, q, metric, keepdims=False):
     """The one distance expression every visit-path oracle and kernel body
     shares: rows-vs-query over the trailing axis, f32.  ``metric``:
     ``"l2"`` squared L2, ``"ip"`` negated inner product (so smaller is
     better for both).  Keeping it a single expression — an elementwise map
     followed by one trailing-axis reduce — is what makes the (V, d) oracle
-    and the per-row (d,) kernel reductions bitwise identical."""
+    and the (rb, d) kernel row-block reductions bitwise identical."""
     if metric == "l2":
         diff = (vec - q).astype(jnp.float32)
-        return jnp.sum(diff * diff, axis=-1)
+        return jnp.sum(diff * diff, axis=-1, keepdims=keepdims)
     if metric == "ip":
-        return jnp.sum(-(vec.astype(jnp.float32) * q.astype(jnp.float32)), axis=-1)
+        return jnp.sum(
+            -(vec.astype(jnp.float32) * q.astype(jnp.float32)), axis=-1, keepdims=keepdims
+        )
     raise ValueError(f"unknown kernel metric {metric!r}; expected 'l2' or 'ip'")
 
 
@@ -81,9 +83,8 @@ def chain_sum_m(parts):
 def subspace_lut(codebooks, q_resid):
     """Per-subspace squared-L2 ADC table: (m, ks, dsub), (d_pad,) -> (m, ks).
 
-    Shared by the jnp scoring path (vmapped in quant/encode.build_luts) and
-    the pq_score kernel's in-kernel LUT construction — one expression, so
-    the two paths agree bitwise.
+    Built once per query (vmapped in quant/encode.build_luts); the jnp
+    scoring path and the pq_score kernel both look up that one table.
     """
     m, _, dsub = codebooks.shape
     qs = q_resid.reshape(m, 1, dsub)
@@ -99,9 +100,8 @@ def subspace_lut_ip(codebooks, q_resid):
     (d_pad,) -> (m, ks).  Summing the m tables reconstructs
     ``-(q · decode(code))`` (codes are raw for ip — quant/params.py rejects
     residual centering off-l2, and the zero-padded tail contributes exact
-    zeros).  Same explicit fold as :func:`subspace_lut`, same sharing
-    contract: the jnp path and the pq_score kernel both call this one
-    expression, so the two scoring paths agree bitwise."""
+    zeros).  Same explicit fold and sharing contract as
+    :func:`subspace_lut`."""
     m, _, dsub = codebooks.shape
     qs = q_resid.reshape(m, 1, dsub)
     prod = codebooks * qs
@@ -117,32 +117,33 @@ def adc_lut(codebooks, q_resid, metric="l2"):
     raise ValueError(f"unknown kernel metric {metric!r}; expected 'l2' or 'ip'")
 
 
-def pq_score_ref(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, metric="l2"):
-    """ADC oracle: LUT build + code-gather scoring + DNF predicate.
+def pq_score_ref(codes, attrs, idx, mask, lut, lo, hi):
+    """ADC oracle: code-gather table lookups + DNF predicate.
 
-    ``codes``: (N + 1, m) uint8 (sentinel row N); sentinel ids are
-    masked-out visits even under a true mask, exactly like
+    ``codes``: (N + 1, m) uint8 (sentinel row N); ``lut``: the query's
+    (m, ks) table (:func:`adc_lut`, which carries the metric).  Sentinel
+    ids are masked-out visits even under a true mask, exactly like
     filter_distance_ref.  Returns (dists (V,) f32 +inf where masked,
     passed (V,) bool).
     """
     n = codes.shape[0] - 1
+    m = lut.shape[0]
     safe = jnp.where(mask, jnp.clip(idx, 0, n), n)
     valid = mask & (safe < n)
-    lut = adc_lut(codebooks, q_resid, metric)  # (m, ks)
     cd = codes[safe].astype(jnp.int32)  # (V, m)
-    vals = lut[jnp.arange(codebooks.shape[0])[None, :], cd]  # (V, m)
-    dist = chain_sum_m([vals[:, mi] for mi in range(codebooks.shape[0])])
+    vals = lut[jnp.arange(m)[None, :], cd]  # (V, m)
+    dist = chain_sum_m([vals[:, mi] for mi in range(m)])
     a = attrs[safe]
     term_ok = jnp.all((a[:, None, :] >= lo[None]) & (a[:, None, :] <= hi[None]), axis=-1)
     passed = jnp.any(term_ok, axis=-1) & valid
     return jnp.where(valid, dist, jnp.inf), passed
 
 
-def pq_score_batch_ref(codes, attrs, idx, mask, q_resid, codebooks, lo, hi, metric="l2"):
-    """Batched (B, V) ADC oracle: per-lane query residuals and bounds."""
+def pq_score_batch_ref(codes, attrs, idx, mask, luts, lo, hi):
+    """Batched (B, V) ADC oracle: per-lane (m, ks) tables and bounds."""
     return jax.vmap(
-        lambda i, m, q, l, h: pq_score_ref(codes, attrs, i, m, q, codebooks, l, h, metric)
-    )(idx, mask, q_resid, lo, hi)
+        lambda i, m, t, l, h: pq_score_ref(codes, attrs, i, m, t, l, h)
+    )(idx, mask, luts, lo, hi)
 
 
 def ivf_score_ref(queries, centroids, metric="l2"):

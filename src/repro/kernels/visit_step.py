@@ -2,11 +2,10 @@
 spot (gather → distance → DNF predicate → tombstone mask → queue-admission
 candidates) in one ``pallas_call``.
 
-``filter_distance`` fused the gather + distance + predicate; the engine
-then still paid two more HBM round-trips per visit batch on the jnp side:
-the tombstone gather ``live[safe]`` and the admission select
-``where(passing, dist, +inf)`` that feeds the result queue.  This kernel
-folds both in and emits exactly what ``engine/state.visit`` merges:
+``filter_distance`` fuses the gather + distance + predicate; the engine
+would then still pay the admission select ``where(passing & live, dist,
++inf)`` that feeds the result queue on the jnp side.  This kernel folds it
+in and emits exactly what ``engine/state.visit`` merges:
 
   * **dist**  — the raw visit distance (+inf where masked/sentinel), fed
     to the traversal queues (CandQ / graph-top) so dead records keep
@@ -14,152 +13,65 @@ folds both in and emits exactly what ``engine/state.visit`` merges:
   * **admit** — ``dist`` where the row is valid, predicate-passing AND
     alive, else +inf — merged into the filtered result queue directly.
 
-TPU design, extending the filter_distance pattern:
-  * candidate ids are scalar-prefetched (PrefetchScalarGridSpec); each
-    grid step gathers a *block of RB rows* — RB separate index-mapped
-    (1, d) row DMAs steered by ``idx[i*RB + j]`` — double-buffered by the
-    pipeline while step i-1 computes.  RB (``rows_per_step``) is the
-    autotuned knob: larger RB amortizes per-step grid overhead, smaller RB
-    keeps the VMEM working set and DMA latency per step low.
-  * distance (squared-L2 or negated inner product, static ``metric``)
-    reduces on the VPU via the same ``ref.row_distance`` expression the
-    oracle uses — bitwise parity by construction.
-  * the tombstone vector rides along as RB index-mapped (1, 1) int32
-    gathers; immutable indices (``live is None``) compile a variant
-    without those operands (trace-time branch, zero cost).
+It runs the shared row-gather pipeline (kernels/row_gather.py): candidate
+ids are scalar-prefetched, each grid step DMAs a *block of RB rows* of
+``vectors`` from HBM into a double-buffered VMEM scratch (the next
+block's DMAs are in flight while this block computes; at a width ``d``
+that is not a multiple of 128 the rows are gathered by XLA instead), and
+the distance
+(squared L2 or negated inner product, static ``metric``) reduces on the
+VPU through the same ``ref.row_distance`` expression the oracle uses —
+bitwise parity in interpret mode.  The attribute rows and tombstone flags
+of the V visited rows (a few bytes each) are gathered by XLA and ride in
+as RB-row blocks; immutable indices (``live is None``) compile a variant
+without the tombstone operand (trace-time branch, zero cost).
 
-VMEM working set per step: RB·(d + A + 1) + d + 2·T·A + O(1) floats —
-e.g. RB=8, d=128, A=8, T=4: ~9.3 KB, far under the ~16 MB budget.  The
-win over the unfused sequence is one kernel launch and zero intermediate
-(V,)-sized HBM traffic between scoring and admission.
+VMEM working set per step: 2·RB·(d + A) + RB + d + 2·T·A floats — e.g.
+RB=8, d=128, A=4, T=4: ~8.6 KB, far under the ~16 MB budget.
 
 Block-size resolution (``rows_per_step=None``) goes through
-``kernels/autotune.py``: pin with ``REPRO_PALLAS_BLOCK_VISIT_STEP="rb=4"``,
-else the measured per-shape table, else RB=4.  RB never changes results —
-every row is computed independently by the same expressions — so tests
-assert bitwise equality across RB values.
+``kernels/autotune.py``: pin with ``REPRO_PALLAS_BLOCK_VISIT_STEP="rb=8"``,
+else the measured per-shape table, else RB=8.  Measurement needs concrete
+arrays: a call with concrete arguments times each candidate on those very
+arguments, while a call being traced (the engine hot path inside
+``compass_search``) never measures and takes the table entry or the
+default.  RB never changes results — every row is computed independently
+by the same expressions — so tests assert bitwise equality across RB
+values.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune
 from .interpret import default_interpret
-from .ref import row_distance
+from .row_gather import gather_score
 
 #: wrapper entries (trace-time inside jit) — benchmarks/bench_kernels.py's
-#: selfcheck asserts this advances when the engine claims the fused path,
-#: catching silent fallbacks to ref on any platform including interpret.
+#: selfcheck and chip_smoke.py assert this advances when the engine claims
+#: the fused path, catching silent fallbacks to ref on any platform.
 TRACE_COUNT = 0
 
-_RB_CANDIDATES = (4, 1, 2, 8)
+_RB_CANDIDATES = (8, 4, 16, 32)
 
 
-def _row_map(j: int, rb: int):
-    def index_map(i, idx_ref):
-        return (idx_ref[i * rb + j], 0)
-
-    return index_map
-
-
-def _kernel(idx_ref, *refs, n, rb, metric, has_live):
-    vec_refs = refs[:rb]
-    attr_refs = refs[rb : 2 * rb]
-    off = 2 * rb
-    if has_live:
-        live_refs = refs[off : off + rb]
-        off += rb
-    q_ref, lo_ref, hi_ref, dist_ref, admit_ref = refs[off : off + 5]
-    i = pl.program_id(0)
-    q = q_ref[0, :]  # (d,) VMEM-resident query
-    lo = lo_ref[...]  # (T, A)
-    hi = hi_ref[...]
-    for j in range(rb):  # static unroll over the RB gathered rows
-        valid = idx_ref[i * rb + j] < n  # sentinel row == masked-out visit
-        vec = vec_refs[j][0, :]  # (d,) gathered row (index-mapped)
-        dist = row_distance(vec, q, metric)
-        attrs = attr_refs[j][0, :]  # (A,)
-        term_ok = jnp.all((attrs[None, :] >= lo) & (attrs[None, :] <= hi), axis=1)
-        admit_ok = valid & jnp.any(term_ok)
-        if has_live:
-            admit_ok = admit_ok & (live_refs[j][0, 0] > 0)
-        dist_ref[j] = jnp.where(valid, dist, jnp.inf)
-        admit_ref[j] = jnp.where(admit_ok, dist, jnp.inf)
-
-
-@functools.partial(jax.jit, static_argnames=("metric", "rb", "has_live", "interpret"))
-def _visit_step(vectors, attrs, live2d, idx, mask, q, lo, hi, *,
-                metric: str, rb: int, has_live: bool, interpret: bool):
-    v = idx.shape[0]
-    n = vectors.shape[0] - 1
-    d = vectors.shape[1]
-    a = attrs.shape[1]
-    t = lo.shape[0]
-    pad = (-v) % rb
-    # pad the visit list to a block multiple with masked sentinel slots
-    # (+inf / +inf rows, sliced off below)
-    idx_p = jnp.pad(idx, (0, pad), constant_values=n)
-    mask_p = jnp.pad(mask, (0, pad), constant_values=False)
-    safe_idx = jnp.where(mask_p, jnp.clip(idx_p, 0, n), n).astype(jnp.int32)
-    vp = v + pad
-    in_specs = [pl.BlockSpec((1, d), _row_map(j, rb)) for j in range(rb)]
-    in_specs += [pl.BlockSpec((1, a), _row_map(j, rb)) for j in range(rb)]
-    operands = [vectors] * rb + [attrs] * rb
-    if has_live:
-        in_specs += [pl.BlockSpec((1, 1), _row_map(j, rb)) for j in range(rb)]
-        operands += [live2d] * rb
-    in_specs += [
-        pl.BlockSpec((1, d), lambda i, idx_ref: (0, 0)),
-        pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-        pl.BlockSpec((t, a), lambda i, idx_ref: (0, 0)),
-    ]
-    operands += [q[None, :], lo, hi]
-    dist, admit = pl.pallas_call(
-        functools.partial(_kernel, n=n, rb=rb, metric=metric, has_live=has_live),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(vp // rb,),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((rb,), lambda i, idx_ref: (i,)),
-                pl.BlockSpec((rb,), lambda i, idx_ref: (i,)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((vp,), jnp.float32),
-            jax.ShapeDtypeStruct((vp,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(safe_idx, *operands)
-    return dist[:v], admit[:v]
-
-
-def _tuned_rb(nrows, d, a, t, v, metric, has_live, interpret) -> int:
-    candidates = [{"rb": r} for r in _RB_CANDIDATES if r <= v or r == 1]
+def _tuned_rb(args, metric: str, interpret: bool) -> int:
+    ids, vectors, attrs, _, lo, _, live = args
+    v = ids.shape[1]
+    candidates = [{"rb": r} for r in _RB_CANDIDATES if r <= max(v, _RB_CANDIDATES[0])]
+    concrete = not any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args))
 
     def measure(cfg):
-        # throwaway concrete arrays of the real shape; runs eagerly even
-        # when this resolves at trace time inside an outer jit
-        vecs = jnp.zeros((nrows, d), jnp.float32)
-        ats = jnp.zeros((nrows, a), jnp.float32)
-        lv = jnp.zeros((nrows, 1) if has_live else (1, 1), jnp.int32)
-        out = _visit_step(
-            vecs, ats, lv,
-            jnp.zeros((v,), jnp.int32), jnp.ones((v,), bool),
-            jnp.zeros((d,), jnp.float32),
-            jnp.zeros((t, a), jnp.float32), jnp.ones((t, a), jnp.float32),
-            metric=metric, rb=cfg["rb"], has_live=has_live, interpret=interpret,
-        )
-        jax.block_until_ready(out)
+        return gather_score(*args, score=metric, emit="admit", rb=cfg["rb"],
+                            interpret=interpret)
 
     cfg = autotune.choose(
-        "visit_step", (nrows, d, a, t, v, metric, has_live, interpret),
-        candidates, measure,
+        "visit_step",
+        (vectors.shape[0], vectors.shape[1], attrs.shape[1], lo.shape[1], v,
+         metric, live is not None, interpret),
+        candidates,
+        measure if concrete else None,
     )
     return cfg["rb"]
 
@@ -182,19 +94,16 @@ def visit_step(
 
     ``rows_per_step=None`` resolves the block size through the autotuner;
     an explicit value always wins.  The interpret default comes from
-    kernels/interpret.py (env overrides, trace-time-baking caveat)."""
+    kernels/interpret.py."""
     global TRACE_COUNT
     if interpret is None:
         interpret = default_interpret()
-    has_live = live is not None
+    n = vectors.shape[0] - 1
+    safe = jnp.where(mask, jnp.clip(idx, 0, n), n).astype(jnp.int32)
+    args = (safe[None], vectors, attrs, q[None, None, :], lo[None], hi[None], live)
     if rows_per_step is None:
-        rows_per_step = _tuned_rb(
-            vectors.shape[0], vectors.shape[1], attrs.shape[1], lo.shape[0],
-            idx.shape[0], metric, has_live, interpret,
-        )
-    live2d = live.astype(jnp.int32)[:, None] if has_live else jnp.zeros((1, 1), jnp.int32)
+        rows_per_step = _tuned_rb(args, metric, interpret)
     TRACE_COUNT += 1
-    return _visit_step(
-        vectors, attrs, live2d, idx, mask, q, lo, hi,
-        metric=metric, rb=rows_per_step, has_live=has_live, interpret=interpret,
-    )
+    dist, admit = gather_score(*args, score=metric, emit="admit", rb=rows_per_step,
+                               interpret=interpret)
+    return dist[0], admit[0]

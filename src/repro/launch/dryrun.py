@@ -205,10 +205,10 @@ def run_compass(multi_pod: bool, verbose: bool = True) -> dict:
     return abstract_distributed_search(mesh, verbose=verbose)
 
 
-def save(rec: dict) -> None:
-    os.makedirs(OUT_DIR, exist_ok=True)
+def save(rec: dict, out_dir: str = OUT_DIR) -> None:
+    os.makedirs(out_dir, exist_ok=True)
     name = f"{rec['arch']}_{rec['shape']}_{rec['mesh']}.json".replace("/", "_")
-    with open(os.path.join(OUT_DIR, name), "w") as f:
+    with open(os.path.join(out_dir, name), "w") as f:
         json.dump(rec, f, indent=1)
 
 
@@ -224,11 +224,13 @@ def main() -> None:
     ap.add_argument("--bf16-params", action="store_true",
                     help="store >=2D weights bf16 (hillclimb variant; "
                          "records land in *_bf16.json)")
+    ap.add_argument("--out-dir", default=OUT_DIR,
+                    help="where the per-cell JSON records are written")
     args = ap.parse_args()
 
     if args.compass:
         for mp in ([False, True] if args.both_meshes else [args.multipod]):
-            save(run_compass(mp))
+            save(run_compass(mp), args.out_dir)
         return
 
     failures = []
@@ -240,7 +242,7 @@ def main() -> None:
             for shape_name in SHAPES:
                 for mp in [False, True] if args.both_meshes else [args.multipod]:
                     try:
-                        save(run_cell(arch, shape_name, mp))
+                        save(run_cell(arch, shape_name, mp), args.out_dir)
                     except Exception as e:  # noqa: BLE001
                         traceback.print_exc()
                         failures.append((arch, shape_name, mp, repr(e)))
@@ -256,7 +258,7 @@ def main() -> None:
     if args.bf16_params:
         rec["variant"] = "bf16_params"
         rec["shape"] = rec["shape"] + "_bf16"
-    save(rec)
+    save(rec, args.out_dir)
 
 
 if __name__ == "__main__":

@@ -26,6 +26,7 @@ wall-clock (``time.time()``) taken outside any trace.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -43,6 +44,7 @@ EVENT_KINDS = (
     "compile",
     "slo_burn",
     "health",
+    "index_build_phase",
 )
 
 
@@ -109,3 +111,13 @@ EVENTS = EventLog(path=os.environ.get("REPRO_OBS_EVENTS") or None)
 def emit(kind: str, **fields) -> dict | None:
     """Emit onto the global :data:`EVENTS` log."""
     return EVENTS.emit(kind, **fields)
+
+
+@contextlib.contextmanager
+def timed(kind: str, **fields):
+    """Emit ``kind`` with the body's wall time as ``wall_s`` (index build
+    phases: ``index_build_phase``).  The body must end in a host sync for
+    the time to cover device work."""
+    t0 = time.perf_counter()
+    yield
+    emit(kind, wall_s=time.perf_counter() - t0, **fields)

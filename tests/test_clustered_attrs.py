@@ -1,6 +1,7 @@
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.clustered_attrs import (
     build_clustered_attrs,

@@ -6,7 +6,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 
@@ -195,10 +196,12 @@ def test_pq_score_metric_parity(metric):
     qr = jnp.asarray(rng.normal(size=m * dsub).astype(np.float32))
     lo = jnp.asarray(rng.uniform(0, 0.5, (t, a)).astype(np.float32))
     hi = jnp.asarray(rng.uniform(0.5, 1.0, (t, a)).astype(np.float32))
+    # the table carries the metric and is built once per query, outside
+    # both paths: kernel and oracle look up the same values and fold them
+    # in the same order, so parity is bitwise by construction
+    lut = jax.jit(lambda c, q: ref.adc_lut(c, q, metric))(codebooks, qr)
     (d_k, p_k), (d_r, p_r) = _both_jitted(
-        lambda *z: ops.pq_score(*z, metric=metric),
-        lambda *z: ref.pq_score_ref(*z, metric),
-        codes, attrs, idx, mask, qr, codebooks, lo, hi,
+        ops.pq_score, ref.pq_score_ref, codes, attrs, idx, mask, lut, lo, hi,
     )
     np.testing.assert_array_equal(np.asarray(d_k), np.asarray(d_r))
     np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
@@ -239,6 +242,40 @@ def test_visit_step_matches_ref(metric, with_live, rb):
     dk = np.asarray(d_k)
     assert np.all(np.isinf(ad) | (ad == dk))
     assert np.all(np.isinf(ad[~np.asarray(mask)]))
+
+
+@pytest.mark.parametrize("metric", ["l2", "ip"])
+@pytest.mark.parametrize("kernel", ["visit_step", "visit_step_live", "filter_distance_batch"])
+def test_dma_route_matches_ref(kernel, metric):
+    """At a lane-aligned width the float32 rows are DMA'd from HBM rather
+    than gathered by XLA (the odd widths above); parity stays bitwise."""
+    from repro.kernels.row_gather import dma_rows
+
+    rng = np.random.default_rng(35)
+    b, n, d, a, t, v = 2, 60, 128, 3, 2, 21
+    assert dma_rows(metric, d) and not dma_rows(metric, 19)
+    vectors, attrs = _mk_corpus(rng, n, d, a)
+    live = jnp.asarray(rng.uniform(size=n + 1) > 0.2)
+    idx = jnp.asarray(rng.integers(0, n + 1, (b, v)).astype(np.int32))
+    mask = jnp.asarray(rng.uniform(size=(b, v)) > 0.3)
+    q = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32))
+    lo = jnp.asarray(rng.uniform(0, 0.5, (b, t, a)).astype(np.float32))
+    hi = jnp.asarray(rng.uniform(0.5, 1.0, (b, t, a)).astype(np.float32))
+    if kernel == "filter_distance_batch":
+        got, want = _both_jitted(
+            lambda *z: ops.filter_distance_batch(*z, metric=metric),
+            lambda *z: ref.filter_distance_batch_ref(*z, metric),
+            vectors, attrs, idx, mask, q, lo, hi,
+        )
+    else:
+        lv = live if kernel == "visit_step_live" else None
+        got, want = _both_jitted(
+            lambda *z: ops.visit_step(*z, metric=metric, rows_per_step=8),
+            lambda *z: ref.visit_step_ref(*z, metric),
+            vectors, attrs, lv, idx[0], mask[0], q[0], lo[0], hi[0],
+        )
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_visit_step_vmapped_matches_ref():
@@ -340,6 +377,46 @@ def test_autotune_disabled_uses_default(monkeypatch):
 
     got = autotune.choose("visit_step", ("z",), [{"rb": 4}, {"rb": 2}], fake_measure)
     assert got == {"rb": 4} and not calls  # candidates[0], nothing measured
+    autotune.clear()
+
+
+def test_autotune_raises_when_every_candidate_is_refused(monkeypatch):
+    from repro.kernels import autotune
+
+    autotune.clear()
+    monkeypatch.setenv("REPRO_PALLAS_AUTOTUNE", "1")
+
+    def refuse(cand):
+        raise ValueError(f"tiling {cand} refused")
+
+    with pytest.raises(RuntimeError, match="every block candidate was refused"):
+        autotune.choose("visit_step", ("refused",), [{"rb": 4}, {"rb": 8}], refuse)
+    assert ("visit_step", ("refused",)) not in autotune._TABLE
+    autotune.clear()
+
+
+def test_autotune_never_times_a_tracer(monkeypatch):
+    """Traced (inside jit) the wrapper takes the default without timing;
+    called with concrete arrays it measures on those very arrays."""
+    from repro.kernels import autotune
+
+    autotune.clear()
+    monkeypatch.setenv("REPRO_PALLAS_AUTOTUNE", "1")
+    rng = np.random.default_rng(34)
+    n, d, a, t, v = 60, 8, 2, 1, 16
+    vectors, attrs = _mk_corpus(rng, n, d, a)
+    idx = jnp.asarray(rng.integers(0, n, v).astype(np.int32))
+    mask = jnp.ones((v,), bool)
+    q = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    lo, hi = jnp.zeros((t, a), jnp.float32), jnp.ones((t, a), jnp.float32)
+    jax.jit(lambda *z: ops.visit_step(*z))(vectors, attrs, None, idx, mask, q, lo, hi)
+    assert not autotune._N_MEASURED and not autotune._TABLE
+    assert {d["source"] for d in autotune.decisions().values()} == {"default"}
+    got = ops.visit_step(vectors, attrs, None, idx, mask, q, lo, hi)
+    assert list(autotune._N_MEASURED.values()) == [1]
+    assert {d["source"] for d in autotune.decisions().values()} == {"measured"}
+    want = ref.visit_step_ref(vectors, attrs, None, idx, mask, q, lo, hi)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=1e-6)
     autotune.clear()
 
 

@@ -571,15 +571,19 @@ def test_metric_fallback_counter_from_backend():
     assert c.value(kernel="ivf_score", reason="metric:hamming") == 1
 
 
-def test_autotune_decision_counters():
+def test_autotune_decision_counters(monkeypatch):
     from repro.kernels import autotune
 
     autotune.clear()
+    monkeypatch.setenv("REPRO_PALLAS_AUTOTUNE", "1")
     cands = [{"rb": 2}, {"rb": 4}]
     autotune.choose("visit_step", (1, 2, 3), cands)  # no measure_fn -> default
+    # a default is not cached: a later concrete call still measures
+    autotune.choose("visit_step", (1, 2, 3), cands, lambda cfg: None)  # measured
     autotune.choose("visit_step", (1, 2, 3), cands)  # cached -> table
     c = obs_reg.registry().get("compass_autotune_total")
     assert c.value(kernel="visit_step", source="default") >= 1
+    assert c.value(kernel="visit_step", source="measured") >= 1
     assert c.value(kernel="visit_step", source="table") >= 1
     autotune.clear()
 

@@ -69,7 +69,6 @@ EP_SCRIPT = textwrap.dedent(
 
 
 @pytest.mark.slow
-@pytest.mark.xfail(strict=False, reason="pre-existing at seed: EP script uses jax APIs absent in pinned jax 0.4.37")
 def test_ep_moe_matches_pjit_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"

@@ -4,7 +4,8 @@ dispatch, and the trustworthy-stats fixes (n_cdist / n_clusters_ranked)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import predicate as P
 from repro.core.baselines import brute_force, recall

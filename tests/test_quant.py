@@ -132,10 +132,11 @@ def test_pq_score_matches_ref_bitwise(n, m, ks, dsub, a, t, v):
     q = jnp.asarray(rng.normal(size=m * dsub).astype(np.float32))
     lo = jnp.asarray(rng.uniform(0, 0.5, (t, a)).astype(np.float32))
     hi = jnp.asarray(rng.uniform(0.5, 1.0, (t, a)).astype(np.float32))
-    # both sides jitted: parity is bitwise inside a compile context (the
-    # eager ref differs by float-contraction choices, not math)
-    d_k, p_k = jax.jit(lambda *z: ops.pq_score(*z))(codes, attrs, idx, mask, q, cb, lo, hi)
-    d_r, p_r = jax.jit(lambda *z: ref.pq_score_ref(*z))(codes, attrs, idx, mask, q, cb, lo, hi)
+    # both sides look up the one (m, ks) table built once per query, so
+    # parity is bitwise by construction
+    lut = ref.adc_lut(cb, q)
+    d_k, p_k = jax.jit(lambda *z: ops.pq_score(*z))(codes, attrs, idx, mask, lut, lo, hi)
+    d_r, p_r = jax.jit(lambda *z: ref.pq_score_ref(*z))(codes, attrs, idx, mask, lut, lo, hi)
     np.testing.assert_array_equal(np.asarray(d_k), np.asarray(d_r))
     np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_r))
 
@@ -153,11 +154,12 @@ def test_pq_score_batch_matches_ref_bitwise(b, n, m, ks, dsub, a, t, v):
     q = jnp.asarray(rng.normal(size=(b, m * dsub)).astype(np.float32))
     lo = jnp.asarray(rng.uniform(0, 0.5, (b, t, a)).astype(np.float32))
     hi = jnp.asarray(rng.uniform(0.5, 1.0, (b, t, a)).astype(np.float32))
+    luts = jax.vmap(lambda qr: ref.adc_lut(cb, qr))(q)
     d_k, p_k = jax.jit(lambda *z: ops.pq_score_batch(*z))(
-        codes, attrs, idx, mask, q, cb, lo, hi
+        codes, attrs, idx, mask, luts, lo, hi
     )
     d_r, p_r = jax.jit(lambda *z: ref.pq_score_batch_ref(*z))(
-        codes, attrs, idx, mask, q, cb, lo, hi
+        codes, attrs, idx, mask, luts, lo, hi
     )
     assert d_k.shape == (b, v) and p_k.shape == (b, v)
     np.testing.assert_array_equal(np.asarray(d_k), np.asarray(d_r))
@@ -173,12 +175,13 @@ def test_pq_score_sentinel_under_true_mask():
     idx = jnp.asarray(np.array([0, n, 5, n], np.int32))  # two sentinels
     mask = jnp.asarray(np.array([True, True, True, True]))
     q = jnp.asarray(rng.normal(size=m * dsub).astype(np.float32))
+    lut = ref.adc_lut(cb, q)
     lo = jnp.full((1, a), -np.inf, jnp.float32)  # vacuous bounds: all pass
     hi = jnp.full((1, a), np.inf, jnp.float32)
     for use_pallas in (False, True):
         d, p = jax.jit(
             lambda *z: ops.pq_score(*z, use_pallas=use_pallas)
-        )(codes, attrs, idx, mask, q, cb, lo, hi)
+        )(codes, attrs, idx, mask, lut, lo, hi)
         d, p = np.asarray(d), np.asarray(p)
         assert np.isinf(d[1]) and np.isinf(d[3])
         assert not p[1] and not p[3]
@@ -316,8 +319,8 @@ def test_quant_none_bitwise_unchanged(corpus, built_index, quant_index):
 
 def test_quant_backend_parity(corpus, quant_index):
     """ref and pallas backends agree bitwise on the quantized path (the
-    pq_score kernel's in-kernel LUT equals the jnp table, and the rerank
-    scan is the existing filter_distance parity surface)."""
+    pq_score kernel looks up the same per-query table as the jnp path, and
+    the rerank scan is the existing filter_distance parity surface)."""
     x, attrs, queries = corpus
     qj = jnp.asarray(queries)
     for workload, tree in sorted(WORKLOADS.items()):

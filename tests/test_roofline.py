@@ -35,7 +35,6 @@ def test_parse_collectives_iota_groups():
     assert out["bytes_by_kind"]["reduce-scatter"] == pytest.approx(8 * 128 * 4 * 7)
 
 
-@pytest.mark.xfail(strict=False, reason="pre-existing at seed: cost_analysis() returns a list under pinned jaxlib 0.4.36")
 def test_cost_analysis_matches_hand_count():
     """flops for an unrolled matmul chain == 2*m*k*n each."""
 

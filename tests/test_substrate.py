@@ -4,7 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.checkpoint.checkpoint import latest_steps, restore, save
 from repro.data.synthetic import DataConfig, SyntheticTokens

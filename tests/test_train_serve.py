@@ -43,7 +43,6 @@ def test_checkpoint_restart_resumes(tmp_path, tiny_cfg):
     np.testing.assert_allclose(resumed[-3:], full[-3:], rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.xfail(strict=False, reason="pre-existing at seed under pinned jax 0.4.37 (see CHANGES.md PR 1)")
 def test_microbatched_equals_single_batch_grads(tiny_cfg):
     """Gradient accumulation invariant: mean of 4 microbatch grads equals
     the full-batch grad (compared pre-optimizer: Adam's rsqrt amplifies
