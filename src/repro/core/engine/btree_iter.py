@@ -10,11 +10,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import staged
+
 from .. import predicate as P
 from ..clustered_attrs import searchsorted_slice
 from . import state as S
 
 
+@staged("engine/bnext")
 def step(index, q, pred, chosen, st: S.EngineState, pm, backend) -> S.EngineState:
     """One B.NEXT pull: fetch up to ``efi`` candidate records and VISIT them."""
     ca = index.cattrs

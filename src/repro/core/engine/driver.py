@@ -37,6 +37,8 @@ import jax.numpy as jnp
 
 from typing import TYPE_CHECKING
 
+from repro.obs.profiling import stage_scope, staged
+
 from .. import predicate as P
 from ..planner import plan as qplan
 from ..quant import encode as Q
@@ -214,6 +216,7 @@ class CompassParams:
         )
 
 
+@staged("engine/open")  # everything before the loop: ranking, state, seeds
 def _search_one(
     index: CompassIndex,
     q,
@@ -344,9 +347,10 @@ def _search_one(
         )
         return st
 
-    st = jax.lax.while_loop(cond, body, st)
-    final_stats = st.stats._replace(n_clusters_ranked=st.rank_pos)
-    return SearchResult(st.res.i[: pm.k], st.res.d[: pm.k], final_stats)
+    with stage_scope("engine/loop"):
+        st = jax.lax.while_loop(cond, body, st)
+        final_stats = st.stats._replace(n_clusters_ranked=st.rank_pos)
+        return SearchResult(st.res.i[: pm.k], st.res.d[: pm.k], final_stats)
 
 
 @functools.partial(jax.jit, static_argnames=("pm",))
@@ -399,10 +403,11 @@ def compass_search_jit(
     # count rather than an unconditional nlist.  The coarse layer stays
     # full-precision under quantization (standard IVF-PQ).
     needs_rank = pm.use_btree or (pm.use_graph and pm.adaptive_entry)
-    if needs_rank:
-        cdists = backend.centroid_scores(index, queries, pm.metric)
-    else:
-        cdists = jnp.zeros((queries.shape[0], index.nlist), jnp.float32)
+    with stage_scope("engine/open"):
+        if needs_rank:
+            cdists = backend.centroid_scores(index, queries, pm.metric)
+        else:
+            cdists = jnp.zeros((queries.shape[0], index.nlist), jnp.float32)
     if quant:
         # per-query ADC tables, built batched outside the vmap
         if luts is None:

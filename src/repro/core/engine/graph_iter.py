@@ -10,6 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import staged
+
 from .. import predicate as P
 from . import state as S
 
@@ -29,6 +31,7 @@ def seed_entries(index, rank, pm):
     return index.graph.entry.astype(jnp.int32)[None]
 
 
+@staged("engine/gnext")
 def expand(index, q, pred, st: S.EngineState, pm, backend) -> S.EngineState:
     """Pop the best `beam` shared-queue candidates and expand per
     neighbourhood passrate (Algorithm 2 lines 12-17; beam == 1 is the
@@ -84,6 +87,7 @@ def expand(index, q, pred, st: S.EngineState, pm, backend) -> S.EngineState:
     return st._replace(last_sel=sel)
 
 
+@staged("engine/gnext")
 def step(index, q, pred, st: S.EngineState, pm, backend):
     """One G.NEXT round of the driver loop.
 

@@ -19,6 +19,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import stage_scope, staged
+
 INF = jnp.inf
 
 
@@ -45,6 +47,7 @@ class FixedQueue(NamedTuple):
     def cap(self) -> int:
         return self.d.shape[0]
 
+    @staged("engine/sort")
     def merge(self, nd: jax.Array, ni: jax.Array) -> "FixedQueue":
         """Merge new (dist, id) entries, keeping the best ``cap``."""
         d = jnp.concatenate([self.d, nd])
@@ -56,6 +59,7 @@ class FixedQueue(NamedTuple):
         """Number of live (finite) entries."""
         return jnp.sum(jnp.isfinite(self.d)).astype(jnp.int32)
 
+    @staged("engine/sort")
     def pop(self, w: int) -> tuple[jax.Array, jax.Array, "FixedQueue"]:
         """Remove the best ``w`` entries; returns (dists, ids, rest)."""
         heads_d, heads_i = self.d[:w], self.i[:w]
@@ -64,6 +68,7 @@ class FixedQueue(NamedTuple):
         return heads_d, heads_i, FixedQueue(d[order], self.i[order])
 
 
+@staged("engine/sort")
 def dedup_new(ids: jax.Array, mask: jax.Array) -> jax.Array:
     """Mask out later duplicate ids within a visit list."""
     ids_masked = jnp.where(mask, ids, jnp.iinfo(jnp.int32).max)
@@ -127,6 +132,7 @@ class EngineState(NamedTuple):
     stats: SearchStats
 
 
+@staged("engine/visit")
 def visit(index, q, pred, st: EngineState, ids, mask, pm, backend) -> EngineState:
     """Algorithm 4 over a fixed-size visit list.
 
@@ -189,7 +195,8 @@ def run_if(pred, f, st: EngineState) -> EngineState:
     selecting computes the same values and keeps the index unbatched.
     Use it for the branches that score rows (they reach the kernels)."""
     new = f(st)
-    return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, st)
+    with stage_scope("engine/select"):
+        return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, st)
 
 
 def res_count(st: EngineState) -> jax.Array:

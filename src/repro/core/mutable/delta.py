@@ -21,6 +21,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import staged
+
 from ..quant.encode import QuantizedVectors
 
 
@@ -51,6 +53,7 @@ class DeltaView(NamedTuple):
         return self.gids.shape[0]
 
 
+@staged("mutable/delta")
 def delta_topk(delta: DeltaView, queries, pred, k: int, metric: str, backend):
     """Exact top-k over the delta segment for a query batch.
 
@@ -72,6 +75,7 @@ def delta_topk(delta: DeltaView, queries, pred, k: int, metric: str, backend):
     return top_g, top_d, jnp.sum(delta.valid).astype(jnp.int32), n_pass
 
 
+@staged("mutable/delta")
 def delta_topk_quantized(
     delta: DeltaView, queries, pred, k: int, metric: str, backend, quant,
     luts=None,

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.obs import events as obs_events
 from repro.obs import registry as obs_registry
+from repro.obs.profiling import stage_scope
 
 from .. import predicate as P
 from ..engine.backend import resolve_backend
@@ -98,8 +99,6 @@ def mutable_search(
     else:
         luts = None
     base = compass_search(index, queries, pred, pm, luts)
-    bg = jnp.take(base_gids, jnp.clip(base.ids, 0, index.n_records), axis=0)
-    bg = jnp.where(jnp.isfinite(base.dists), bg, jnp.int32(GID_SENTINEL))
     if quant_delta:
         dg, dd, n_adc, n_rr, n_pass = delta_topk_quantized(
             delta, queries, pred, pmr.k, pmr.metric, backend, pm.quant, luts,
@@ -119,10 +118,13 @@ def mutable_search(
             n_dist=base.stats.n_dist + n_scanned,
             n_pass=base.stats.n_pass + n_pass,
         )
-    all_d = jnp.concatenate([base.dists, dd], axis=1)
-    all_g = jnp.concatenate([bg, dg], axis=1)
-    neg, sel = jax.lax.top_k(-all_d, pmr.k)
-    return SearchResult(jnp.take_along_axis(all_g, sel, axis=1), -neg, stats)
+    with stage_scope("mutable/delta"):  # the base/delta merge
+        bg = jnp.take(base_gids, jnp.clip(base.ids, 0, index.n_records), axis=0)
+        bg = jnp.where(jnp.isfinite(base.dists), bg, jnp.int32(GID_SENTINEL))
+        all_d = jnp.concatenate([base.dists, dd], axis=1)
+        all_g = jnp.concatenate([bg, dg], axis=1)
+        neg, sel = jax.lax.top_k(-all_d, pmr.k)
+        return SearchResult(jnp.take_along_axis(all_g, sel, axis=1), -neg, stats)
 
 
 class MutableIndex:

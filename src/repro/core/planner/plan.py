@@ -37,6 +37,8 @@ from typing import TYPE_CHECKING, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import staged
+
 from .. import predicate as P
 from ..engine.state import dedup_new
 from . import estimate as E
@@ -170,6 +172,7 @@ def plan_query(index: CompassIndex, pred_lo, pred_hi, pm, quant: bool = False) -
     return QueryPlan(mode, est_sel, run_total, ids, mask)
 
 
+@staged("planner")
 def plan_batch(
     index: CompassIndex, queries, pred: P.Predicate, pm, backend, luts=None
 ) -> PlannedBatch:

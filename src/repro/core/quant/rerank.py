@@ -22,6 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import staged
+
 from .encode import decode
 
 
@@ -66,6 +68,7 @@ def rerank_candidates(view, queries, pred, ids, dists1, mask, k, metric, backend
     return sel, -neg, n_rerank
 
 
+@staged("quant/rerank")
 def rerank_batch(index, queries, pred, res, k: int, metric: str, backend, mode: str):
     """Exact rerank of a stage-one SearchResult -> top-``k`` SearchResult.
 

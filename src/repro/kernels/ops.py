@@ -9,9 +9,9 @@ falls back to the jnp oracle — search code paths stay identical either
 way.
 
 Observability (obs/profiling.py): every kernel launch is wrapped in
-``obs.kernel_scope`` — a ``jax.named_scope`` + ``TraceAnnotation`` pair
-(pure metadata; the compiled program is identical) plus a per-kernel
-wrapper counter, and every reference-path fallback (``use_pallas=False``)
+``obs.kernel_scope`` — the ``compass/<kernel>`` named scope (pure HLO
+metadata; the compiled program is identical) plus a per-kernel wrapper
+counter, and every reference-path fallback (``use_pallas=False``)
 bumps ``compass_kernel_fallback_total{kernel,reason}``.  Both record at
 wrapper-call time — inside a jit that is *trace time*, once per compile,
 the same semantics as the ``visit_step.TRACE_COUNT`` CI tripwire.

@@ -11,8 +11,10 @@ bitwise-invariant to search results:
 * **trace** — per-query explain traces: ``compass_search(...,
   explain=True)`` returns :class:`QueryTrace` records rendered by
   :func:`explain` (re-exported as ``repro.compass.explain``).
-* **profiling** — ``jax.named_scope``/``TraceAnnotation`` wrappers around
-  every Pallas kernel and the serving micro-batch, an
+* **profiling** — ``compass/<stage>`` named scopes on the engine's stages
+  and every Pallas kernel (HLO metadata only), ``TraceAnnotation`` host
+  spans around the serving micro-batch and its phases, the record of the
+  programs served (``SERVED``) for joining a device trace to them, an
   ``REPRO_OBS_PROFILE`` XPlane capture helper, and trace-time
   kernel/fallback/autotune counters that stay on even when the registry
   is disabled (one dict add per *compile*).
@@ -39,9 +41,12 @@ from .events import EVENTS, EventLog, emit
 from .health import DEFAULT_WATCHDOGS, HealthCheck, HealthReport, Monitor
 from .profiling import (
     KERNELS,
+    SERVED,
     annotate,
     kernel_scope,
     profile_capture,
+    stage_scope,
+    staged,
 )
 from .registry import (
     LATENCY_BUCKETS_S,
@@ -84,6 +89,7 @@ __all__ = [
     "QueryTrace",
     "RECALL_BUCKETS",
     "SCHEMA",
+    "SERVED",
     "ShardedQueryTrace",
     "SloSpec",
     "SloWindow",
@@ -109,6 +115,8 @@ __all__ = [
     "reset",
     "set_enabled",
     "slo",
+    "stage_scope",
+    "staged",
     "timeseries",
     "trace",
     "validate_export",

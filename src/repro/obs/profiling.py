@@ -1,15 +1,25 @@
-"""Kernel profiling hooks: named scopes, trace capture, route counters.
+"""Profiling hooks: stage scopes, host spans, trace capture, route counters.
 
-Three layers, all result-invariant:
+All result-invariant:
 
+* :func:`stage_scope` (and its decorator form :func:`staged`) puts the
+  HLO instructions traced inside it under ``compass/<stage>`` in their
+  ``op_name`` metadata: the engine's stages (``engine/open``,
+  ``engine/loop``, ``engine/bnext``, ``engine/gnext``, ``engine/visit``,
+  ``engine/sort``, ``engine/select``), ``planner``, ``mutable/delta`` and
+  ``quant/rerank``.  ``jax.named_scope`` only decorates metadata, so the
+  compiled program is identical with or without the scope.
 * :func:`kernel_scope` wraps each Pallas kernel wrapper (kernels/ops.py)
-  in ``jax.named_scope`` (HLO metadata — the kernel shows up under
-  ``compass/<name>`` in a device trace) plus ``jax.profiler
-  .TraceAnnotation`` (host timeline), and bumps the per-kernel wrapper
-  counter.  named_scope only decorates metadata on ops traced inside it,
-  so the compiled program is identical with or without the scope.
+  in the stage scope ``compass/<kernel>`` and bumps the per-kernel wrapper
+  counter.  It opens no host span: the wrapper runs once per compile,
+  while JAX traces the program, never per launch.
 * :func:`annotate` is the host-phase sibling (no HLO scope) used around
-  the serving micro-batch dispatch.
+  the serving micro-batch (``compass/serve_batch/B{B}xT{T}``) and the
+  phases around it (``compass/serve/{writes,pack,unpack,gauges}``).
+* :data:`SERVED` records, by the label of the ``compass/serve_batch``
+  span they ran under, how to get the HLO text of the programs the
+  process served, so a trace reader can join device events to the
+  instructions, and their scopes, by name.
 * :func:`profile_capture` drives ``jax.profiler.start_trace`` /
   ``stop_trace`` and dumps an XPlane trace dir (load it in TensorBoard or
   convert to perfetto) when ``REPRO_OBS_PROFILE`` is set — either ``1``
@@ -26,7 +36,9 @@ trace would otherwise be invisible forever, the cost is a dict add per
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
+from typing import Callable
 
 import jax
 
@@ -73,13 +85,31 @@ def count_autotune(kernel: str, source: str) -> None:
     ).inc(1, kernel=kernel, source=source)
 
 
+def stage_scope(stage: str):
+    """``jax.named_scope("compass/<stage>")``: the one way a stage of the
+    program names its HLO instructions (a fresh scope object per use)."""
+    return jax.named_scope(f"compass/{stage}")
+
+
+def staged(stage: str):
+    """Decorator form of :func:`stage_scope`: trace ``fn`` inside it."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with stage_scope(stage):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
 @contextlib.contextmanager
 def kernel_scope(name: str):
-    """Wrap one kernel launch: named_scope + TraceAnnotation + counter."""
+    """Wrap one kernel wrapper call: stage scope + counter."""
     count_kernel(name)
-    with jax.named_scope(f"compass/{name}"), jax.profiler.TraceAnnotation(
-        f"compass/{name}"
-    ):
+    with stage_scope(name):
         yield
 
 
@@ -88,6 +118,30 @@ def annotate(name: str):
     """Host-phase timeline annotation (serving micro-batch path)."""
     with jax.profiler.TraceAnnotation(name):
         yield
+
+
+class ServedPrograms:
+    """The programs a process served, by the label of the
+    ``compass/serve_batch/B{B}xT{T}`` span each ran under, as zero-argument
+    callables that return its compiled HLO text.  A callable holds abstract
+    shapes, static params or an executable, never an array, so it outlives
+    the service without keeping its data on the device.  The latest program
+    recorded under a label wins."""
+
+    def __init__(self):
+        self._text_fns: dict[str, Callable[[], str]] = {}
+
+    def record(self, label: str, text_fn: Callable[[], str]) -> None:
+        self._text_fns[label] = text_fn
+
+    def texts(self) -> dict[str, str]:
+        """``{label: compiled HLO text}`` of every program recorded."""
+        return {label: fn() for label, fn in sorted(self._text_fns.items())}
+
+
+#: process-wide, like the registry and the event log: a trace is read after
+#: the service that served it is gone
+SERVED = ServedPrograms()
 
 
 def profile_dir() -> str | None:

@@ -340,7 +340,8 @@ class SearchService:
         the deadline.  Returns the results completed this round (also
         retrievable via :meth:`poll`)."""
         if self.mutable is not None:
-            self.apply_writes()
+            with obs_prof.annotate("compass/serve/writes"):
+                self.apply_writes()
         done: list[ServiceResult] = []
         now = self.clock()
         for t_bucket, q in self._queues.items():
@@ -352,13 +353,15 @@ class SearchService:
             # after dispatch so this round's sync-point records are in the
             # snapshot; Monitor.tick is a no-op when obs is disabled and
             # rate-limited by its interval_s otherwise
-            self.monitor.tick()
+            with obs_prof.annotate("compass/serve/gauges"):
+                self.monitor.tick()
         return done
 
     def flush(self) -> list[ServiceResult]:
         """Dispatch everything queued regardless of deadlines (drain)."""
         if self.mutable is not None:
-            self.apply_writes()
+            with obs_prof.annotate("compass/serve/writes"):
+                self.apply_writes()
         done: list[ServiceResult] = []
         for t_bucket, q in self._queues.items():
             while q:
@@ -428,16 +431,18 @@ class SearchService:
         return exe
 
     def _dispatch(self, t_bucket: int, full: bool) -> list[ServiceResult]:
-        q = self._queues[t_bucket]
-        jobs = [q.popleft() for _ in range(min(self.batch_size, len(q)))]
         B = self.batch_size
-        n_fill = B - len(jobs)
-        queries = np.zeros((B, self.index.dim), np.float32)
-        for i, job in enumerate(jobs):
-            queries[i] = job.query
-        preds = [j.pred for j in jobs] + [P.never_true(self.index.n_attrs)] * n_fill
-        pred = P.stack_predicates(preds, n_terms=t_bucket)
-        qj = jnp.asarray(queries)
+        label = f"B{B}xT{t_bucket}"
+        with obs_prof.annotate("compass/serve/pack"):
+            q = self._queues[t_bucket]
+            jobs = [q.popleft() for _ in range(min(self.batch_size, len(q)))]
+            n_fill = B - len(jobs)
+            queries = np.zeros((B, self.index.dim), np.float32)
+            for i, job in enumerate(jobs):
+                queries[i] = job.query
+            preds = [j.pred for j in jobs] + [P.never_true(self.index.n_attrs)] * n_fill
+            pred = P.stack_predicates(preds, n_terms=t_bucket)
+            qj = jnp.asarray(queries)
 
         t0 = self.clock()
         epoch = None
@@ -463,92 +468,98 @@ class SearchService:
                      snap.index.n_records, snap.delta.cap),
                     None,
                 )
-            with obs_prof.annotate(f"compass/serve_batch/B{B}xT{t_bucket}"):
+            with obs_prof.annotate(f"compass/serve_batch/{label}"):
                 res = mutable_search(
                     snap.index, snap.base_gids, snap.delta, qj, pred, self.params
                 )
                 res.ids.block_until_ready()
         else:
             exe = self._executable(qj, pred)
-            with obs_prof.annotate(f"compass/serve_batch/B{B}xT{t_bucket}"):
+            with obs_prof.annotate(f"compass/serve_batch/{label}"):
                 res = exe(self.index, qj, pred)
                 res.ids.block_until_ready()
         exec_s = self.clock() - t0
 
-        st = self._stats[(B, t_bucket)]
-        st.n_requests += len(jobs)
-        st.n_batches += 1
-        st.n_fillers += n_fill
-        st.n_full_flush += int(full)
-        st.n_deadline_flush += int(not full)
-        st.total_exec_s += exec_s
-        # planner-chosen execution mode per real lane (filler lanes are the
-        # service's padding, not traffic — excluded from the counters)
-        modes = np.asarray(res.stats.mode)[: len(jobs)]
-        st.n_mode_prefilter += int(np.sum(modes == plan_mod.PREFILTER))
-        st.n_mode_cooperative += int(np.sum(modes == plan_mod.COOPERATIVE))
-        st.n_mode_postfilter += int(np.sum(modes == plan_mod.POSTFILTER))
+        with obs_prof.annotate("compass/serve/unpack"):
+            st = self._stats[(B, t_bucket)]
+            st.n_requests += len(jobs)
+            st.n_batches += 1
+            st.n_fillers += n_fill
+            st.n_full_flush += int(full)
+            st.n_deadline_flush += int(not full)
+            st.total_exec_s += exec_s
+            # planner-chosen execution mode per real lane (filler lanes are the
+            # service's padding, not traffic — excluded from the counters)
+            modes = np.asarray(res.stats.mode)[: len(jobs)]
+            st.n_mode_prefilter += int(np.sum(modes == plan_mod.PREFILTER))
+            st.n_mode_cooperative += int(np.sum(modes == plan_mod.COOPERATIVE))
+            st.n_mode_postfilter += int(np.sum(modes == plan_mod.POSTFILTER))
 
+            ids = np.asarray(res.ids)
+            dists = np.asarray(res.dists)
+            out = []
+            for i, job in enumerate(jobs):
+                wait = t0 - job.t_submit
+                st.total_wait_s += wait
+                r = ServiceResult(
+                    rid=job.rid,
+                    ids=ids[i, : job.k].copy(),
+                    dists=dists[i, : job.k].copy(),
+                    bucket=(B, t_bucket),
+                    queue_wait_s=wait,
+                    batch_exec_s=exec_s,
+                    epoch=epoch,
+                )
+                self._results[job.rid] = r
+                out.append(r)
+            while len(self._results) > self.result_buffer:
+                self._results.popitem(last=False)  # evict oldest unpolled
         if obs_reg.enabled():
-            # we are already at the batch's sync point (block_until_ready
-            # above), so folding device stats into host counters adds no
-            # extra synchronization.  Filler lanes are the service's
-            # padding, not traffic: slice them off before recording, same
-            # rule as the mode counters above.
-            bname = f"B{B}xT{t_bucket}"
-            lanes = len(jobs)
-            sliced = jax.tree_util.tree_map(
-                lambda a: np.asarray(a)[:lanes], res.stats
-            )
-            obs_reg.record_search_stats(sliced, labels={"bucket": bname})
-            # the serve families share their declaration with the
-            # multi-tenant CollectionService: same (bucket, tenant)
-            # schema, this single-index service recording tenant="" (the
-            # unset-value convention record_search_stats already uses)
-            R = obs_reg.registry()
-            R.counter(
-                "compass_serve_requests_total", "Real requests served",
-                labelnames=("bucket", "tenant"),
-            ).inc(lanes, bucket=bname, tenant="")
-            R.counter(
-                "compass_serve_batches_total", "Micro-batches dispatched",
-                labelnames=("bucket", "tenant"),
-            ).inc(bucket=bname, tenant="")
-            if n_fill:
+            with obs_prof.annotate("compass/serve/gauges"):
+                # we are already at the batch's sync point (block_until_ready
+                # above), so folding device stats into host counters adds no
+                # extra synchronization.  Filler lanes are the service's
+                # padding, not traffic: slice them off before recording, same
+                # rule as the mode counters above.
+                lanes = len(jobs)
+                sliced = jax.tree_util.tree_map(
+                    lambda a: np.asarray(a)[:lanes], res.stats
+                )
+                obs_reg.record_search_stats(sliced, labels={"bucket": label})
+                # the serve families share their declaration with the
+                # multi-tenant CollectionService: same (bucket, tenant)
+                # schema, this single-index service recording tenant="" (the
+                # unset-value convention record_search_stats already uses)
+                R = obs_reg.registry()
                 R.counter(
-                    "compass_serve_fillers_total", "Padded filler lanes dispatched",
+                    "compass_serve_requests_total", "Real requests served",
                     labelnames=("bucket", "tenant"),
-                ).inc(n_fill, bucket=bname, tenant="")
-            R.histogram(
-                "compass_serve_exec_seconds", "Micro-batch execution wall time",
-                labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
-            ).observe(exec_s, bucket=bname, tenant="")
-            wait_h = R.histogram(
-                "compass_serve_wait_seconds", "Per-request queue wait",
-                labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
-            )
-            for job in jobs:
-                wait_h.observe(t0 - job.t_submit, bucket=bname, tenant="")
-
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        out = []
-        for i, job in enumerate(jobs):
-            wait = t0 - job.t_submit
-            st.total_wait_s += wait
-            r = ServiceResult(
-                rid=job.rid,
-                ids=ids[i, : job.k].copy(),
-                dists=dists[i, : job.k].copy(),
-                bucket=(B, t_bucket),
-                queue_wait_s=wait,
-                batch_exec_s=exec_s,
-                epoch=epoch,
-            )
-            self._results[job.rid] = r
-            out.append(r)
-        while len(self._results) > self.result_buffer:
-            self._results.popitem(last=False)  # evict oldest unpolled
+                ).inc(lanes, bucket=label, tenant="")
+                R.counter(
+                    "compass_serve_batches_total", "Micro-batches dispatched",
+                    labelnames=("bucket", "tenant"),
+                ).inc(bucket=label, tenant="")
+                if n_fill:
+                    R.counter(
+                        "compass_serve_fillers_total", "Padded filler lanes dispatched",
+                        labelnames=("bucket", "tenant"),
+                    ).inc(n_fill, bucket=label, tenant="")
+                R.histogram(
+                    "compass_serve_exec_seconds", "Micro-batch execution wall time",
+                    labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
+                ).observe(exec_s, bucket=label, tenant="")
+                wait_h = R.histogram(
+                    "compass_serve_wait_seconds", "Per-request queue wait",
+                    labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
+                )
+                for job in jobs:
+                    wait_h.observe(t0 - job.t_submit, bucket=label, tenant="")
+                # the batched loop runs until its slowest lane is done: the
+                # iterations it ran are the largest n_steps, fillers included
+                R.counter(
+                    "compass_loop_steps_total", "Engine loop iterations run",
+                    labelnames=("bucket", "tenant"),
+                ).inc(int(np.max(np.asarray(res.stats.n_steps))), bucket=label, tenant="")
         return out
 
     # -- observability -------------------------------------------------------

@@ -44,6 +44,7 @@ builds per-tenant burn-rate objectives from the same labels.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import OrderedDict, deque
@@ -182,6 +183,20 @@ class _Collection:
         return sum(len(q) for q in self.queues.values())
 
 
+def _abstract(tree):
+    """The shapes, dtypes and placements of ``tree``'s arrays, no data: what
+    ``jit`` keys its compiled programs on (an uncommitted array has no
+    placement of its own)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if getattr(a, "committed", False) else None),
+        tree)
+
+
+def _compiled_text(fn, args, params) -> str:
+    return fn.lower(*args, params).compile().as_text()
+
+
 def _index_sig(index: CompassIndex) -> tuple:
     """Hashable shape/dtype signature of an index pytree — the part of
     the AOT executable key that makes cross-tenant sharing safe: two
@@ -287,6 +302,8 @@ class CollectionService:
         self._collections: dict[str, _Collection] = {}
         self._executables: dict[tuple, Callable] = {}  # immutable AOT, shared
         self._mutable_shapes: set[tuple] = set()  # mutable jit shapes, shared
+        # serve_batch label -> how to get the compiled HLO text of its program
+        self._programs: dict[str, Callable[[], str]] = {}
         self._results: OrderedDict[int, TenantResult] = OrderedDict()
         self._cache_served: list[TenantResult] = []
         self._rid = itertools.count()
@@ -638,10 +655,11 @@ class CollectionService:
         deliver pending cache hits, then dispatch ready micro-batches in
         weighted-fair order (at most ``max_batches_per_step`` when set).
         """
-        for col in self._collections.values():
-            if col.mutable is not None:
-                self._apply_writes(col)
-            self._check_epoch(col)
+        with obs_prof.annotate("compass/serve/writes"):
+            for col in self._collections.values():
+                if col.mutable is not None:
+                    self._apply_writes(col)
+                self._check_epoch(col)
         done = self._drain_cache_served()
         now = self.clock()
         budget = self.max_batches_per_step or float("inf")
@@ -653,18 +671,20 @@ class CollectionService:
             done.extend(self._dispatch(col, tb, full))
             self._charge(col)
             budget -= 1
-        self._publish_gauges()
-        if self.monitor is not None:
-            self.monitor.tick()
+        with obs_prof.annotate("compass/serve/gauges"):
+            self._publish_gauges()
+            if self.monitor is not None:
+                self.monitor.tick()
         return done
 
     def flush(self) -> list[TenantResult]:
         """Dispatch everything queued regardless of deadlines, still in
         weighted-fair order (drain)."""
-        for col in self._collections.values():
-            if col.mutable is not None:
-                self._apply_writes(col)
-            self._check_epoch(col)
+        with obs_prof.annotate("compass/serve/writes"):
+            for col in self._collections.values():
+                if col.mutable is not None:
+                    self._apply_writes(col)
+                self._check_epoch(col)
         done = self._drain_cache_served()
         while True:
             ready = [
@@ -684,7 +704,8 @@ class CollectionService:
                 self._dispatch(col, tb, full=len(col.queues[tb]) >= self.batch_size)
             )
             self._charge(col)
-        self._publish_gauges()
+        with obs_prof.annotate("compass/serve/gauges"):
+            self._publish_gauges()
         return done
 
     def run_until_idle(self) -> list[TenantResult]:
@@ -722,19 +743,34 @@ class CollectionService:
                 labelnames=("cache",),
             ).inc(cache=cache)
 
+    def _record_program(self, label: str, text_fn: Callable[[], str]) -> None:
+        self._programs[label] = text_fn
+        obs_prof.SERVED.record(label, text_fn)
+
+    def program_texts(self) -> dict[str, str]:
+        """``{"B{B}xT{T}": compiled HLO text}`` of the program each bucket
+        label served, the label of its ``compass/serve_batch`` span: from
+        the cached executable on the AOT path, and on the mutable path from
+        ``mutable_search`` lowered again on the abstract shapes of the
+        bucket's first dispatch (a compile-cache hit).  For joining a
+        device trace to the instructions, and their stage scopes, by name."""
+        return {label: self._programs[label]() for label in sorted(self._programs)}
+
     def _dispatch(self, col: _Collection, t_bucket: int, full: bool) -> list[TenantResult]:
         name = col.spec.name
         index = col.index
-        q = col.queues[t_bucket]
-        jobs = [q.popleft() for _ in range(min(self.batch_size, len(q)))]
         B = self.batch_size
-        n_fill = B - len(jobs)
-        queries = np.zeros((B, index.dim), np.float32)
-        for i, job in enumerate(jobs):
-            queries[i] = job.query
-        preds = [j.pred for j in jobs] + [P.never_true(index.n_attrs)] * n_fill
-        pred = P.stack_predicates(preds, n_terms=t_bucket)
-        qj = jnp.asarray(queries)
+        label = f"B{B}xT{t_bucket}"
+        with obs_prof.annotate("compass/serve/pack"):
+            q = col.queues[t_bucket]
+            jobs = [q.popleft() for _ in range(min(self.batch_size, len(q)))]
+            n_fill = B - len(jobs)
+            queries = np.zeros((B, index.dim), np.float32)
+            for i, job in enumerate(jobs):
+                queries[i] = job.query
+            preds = [j.pred for j in jobs] + [P.never_true(index.n_attrs)] * n_fill
+            pred = P.stack_predicates(preds, n_terms=t_bucket)
+            qj = jnp.asarray(queries)
 
         t0 = self.clock()
         epoch = None
@@ -757,7 +793,10 @@ class CollectionService:
                     (B, t_bucket, pred.lo.shape[-1],
                      snap.index.n_records, snap.delta.cap),
                 )
-            with obs_prof.annotate(f"compass/serve_batch/B{B}xT{t_bucket}"):
+                args = (snap.index, snap.base_gids, snap.delta, qj, pred)
+                self._record_program(label, functools.partial(
+                    _compiled_text, mutable_search, _abstract(args), col.params))
+            with obs_prof.annotate(f"compass/serve_batch/{label}"):
                 res = mutable_search(
                     snap.index, snap.base_gids, snap.delta, qj, pred, col.params
                 )
@@ -770,83 +809,90 @@ class CollectionService:
                 self._executables[key] = exe
                 st.n_compiles += 1
                 self._record_compile("aot", (B, t_bucket, pred.lo.shape[-1]))
+                self._record_program(label, exe.as_text)
             else:
                 st.n_cache_hits += 1
-            with obs_prof.annotate(f"compass/serve_batch/B{B}xT{t_bucket}"):
+            with obs_prof.annotate(f"compass/serve_batch/{label}"):
                 res = exe(index, qj, pred)
                 res.ids.block_until_ready()
         exec_s = self.clock() - t0
 
-        st.n_requests += len(jobs)
-        st.n_batches += 1
-        st.n_fillers += n_fill
-        st.n_full_flush += int(full)
-        st.n_deadline_flush += int(not full)
-        st.total_exec_s += exec_s
-        modes = np.asarray(res.stats.mode)[: len(jobs)]
-        st.n_mode_prefilter += int(np.sum(modes == plan_mod.PREFILTER))
-        st.n_mode_cooperative += int(np.sum(modes == plan_mod.COOPERATIVE))
-        st.n_mode_postfilter += int(np.sum(modes == plan_mod.POSTFILTER))
+        with obs_prof.annotate("compass/serve/unpack"):
+            st.n_requests += len(jobs)
+            st.n_batches += 1
+            st.n_fillers += n_fill
+            st.n_full_flush += int(full)
+            st.n_deadline_flush += int(not full)
+            st.total_exec_s += exec_s
+            modes = np.asarray(res.stats.mode)[: len(jobs)]
+            st.n_mode_prefilter += int(np.sum(modes == plan_mod.PREFILTER))
+            st.n_mode_cooperative += int(np.sum(modes == plan_mod.COOPERATIVE))
+            st.n_mode_postfilter += int(np.sum(modes == plan_mod.POSTFILTER))
 
-        if obs_reg.enabled():
-            bname = f"B{B}xT{t_bucket}"
-            lanes = len(jobs)
-            sliced = jax.tree_util.tree_map(
-                lambda a: np.asarray(a)[:lanes], res.stats
-            )
-            obs_reg.record_search_stats(
-                sliced, labels={"bucket": bname, "tenant": name}
-            )
-            R = obs_reg.registry()
-            R.counter(
-                "compass_serve_requests_total", "Real requests served",
-                labelnames=("bucket", "tenant"),
-            ).inc(lanes, bucket=bname, tenant=name)
-            R.counter(
-                "compass_serve_batches_total", "Micro-batches dispatched",
-                labelnames=("bucket", "tenant"),
-            ).inc(bucket=bname, tenant=name)
-            if n_fill:
-                R.counter(
-                    "compass_serve_fillers_total", "Padded filler lanes dispatched",
-                    labelnames=("bucket", "tenant"),
-                ).inc(n_fill, bucket=bname, tenant=name)
-            R.histogram(
-                "compass_serve_exec_seconds", "Micro-batch execution wall time",
-                labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
-            ).observe(exec_s, bucket=bname, tenant=name)
-            wait_h = R.histogram(
-                "compass_serve_wait_seconds", "Per-request queue wait",
-                labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
-            )
-            for job in jobs:
-                wait_h.observe(t0 - job.t_submit, bucket=bname, tenant=name)
-
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        out = []
-        for i, job in enumerate(jobs):
-            wait = t0 - job.t_submit
-            st.total_wait_s += wait
-            r = TenantResult(
-                rid=job.rid,
-                collection=name,
-                ids=ids[i, : job.k].copy(),
-                dists=dists[i, : job.k].copy(),
-                bucket=(B, t_bucket),
-                queue_wait_s=wait,
-                batch_exec_s=exec_s,
-                epoch=epoch,
-            )
-            self._store(r)
-            out.append(r)
-            if job.exact_key is not None:
-                # cache the engine's full-k row so the entry replays the
-                # exact bytes the live path would have truncated from
-                col.cache.insert(
-                    job.exact_key, job.near_key,
-                    ids[i].copy(), dists[i].copy(), epoch=epoch,
+            ids = np.asarray(res.ids)
+            dists = np.asarray(res.dists)
+            out = []
+            for i, job in enumerate(jobs):
+                wait = t0 - job.t_submit
+                st.total_wait_s += wait
+                r = TenantResult(
+                    rid=job.rid,
+                    collection=name,
+                    ids=ids[i, : job.k].copy(),
+                    dists=dists[i, : job.k].copy(),
+                    bucket=(B, t_bucket),
+                    queue_wait_s=wait,
+                    batch_exec_s=exec_s,
+                    epoch=epoch,
                 )
+                self._store(r)
+                out.append(r)
+                if job.exact_key is not None:
+                    # cache the engine's full-k row so the entry replays the
+                    # exact bytes the live path would have truncated from
+                    col.cache.insert(
+                        job.exact_key, job.near_key,
+                        ids[i].copy(), dists[i].copy(), epoch=epoch,
+                    )
+        if obs_reg.enabled():
+            with obs_prof.annotate("compass/serve/gauges"):
+                lanes = len(jobs)
+                sliced = jax.tree_util.tree_map(
+                    lambda a: np.asarray(a)[:lanes], res.stats
+                )
+                obs_reg.record_search_stats(
+                    sliced, labels={"bucket": label, "tenant": name}
+                )
+                R = obs_reg.registry()
+                R.counter(
+                    "compass_serve_requests_total", "Real requests served",
+                    labelnames=("bucket", "tenant"),
+                ).inc(lanes, bucket=label, tenant=name)
+                R.counter(
+                    "compass_serve_batches_total", "Micro-batches dispatched",
+                    labelnames=("bucket", "tenant"),
+                ).inc(bucket=label, tenant=name)
+                if n_fill:
+                    R.counter(
+                        "compass_serve_fillers_total", "Padded filler lanes dispatched",
+                        labelnames=("bucket", "tenant"),
+                    ).inc(n_fill, bucket=label, tenant=name)
+                R.histogram(
+                    "compass_serve_exec_seconds", "Micro-batch execution wall time",
+                    labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
+                ).observe(exec_s, bucket=label, tenant=name)
+                wait_h = R.histogram(
+                    "compass_serve_wait_seconds", "Per-request queue wait",
+                    labelnames=("bucket", "tenant"), buckets=obs_reg.LATENCY_BUCKETS_S,
+                )
+                for job in jobs:
+                    wait_h.observe(t0 - job.t_submit, bucket=label, tenant=name)
+                # the batched loop runs until its slowest lane is done: the
+                # iterations it ran are the largest n_steps, fillers included
+                R.counter(
+                    "compass_loop_steps_total", "Engine loop iterations run",
+                    labelnames=("bucket", "tenant"),
+                ).inc(int(np.max(np.asarray(res.stats.n_steps))), bucket=label, tenant=name)
         return out
 
     # -- observability -------------------------------------------------------

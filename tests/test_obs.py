@@ -503,6 +503,58 @@ def test_service_records_batch_metrics():
     assert obs_reg.validate_export(r.to_json()) == []
 
 
+@pytest.mark.parametrize("front_door", ["search_service", "collection_service"])
+def test_loop_steps_counter_is_the_batched_loop_iterations(front_door, monkeypatch):
+    """``compass_loop_steps_total`` adds, per micro-batch, the largest
+    ``n_steps`` over all B lanes, fillers included: the iterations the
+    batched loop ran.  Recorded only while the registry is enabled."""
+    import importlib
+
+    from repro.core.mutable import mutable_search
+    from repro.serving.tenancy import CollectionService
+
+    module = importlib.import_module(
+        "repro.serving.search_service" if front_door == "search_service"
+        else "repro.serving.tenancy.service")
+    ran = []
+
+    def spy(*args, **kwargs):
+        res = mutable_search(*args, **kwargs)
+        ran.append(np.asarray(res.stats.n_steps))
+        return res
+
+    monkeypatch.setattr(module, "mutable_search", spy)
+    svc, rng, d, _ = _service(mutable=True)
+    if front_door == "collection_service":
+        coll = CollectionService(svc.params, batch_size=4, max_wait_s=0.0)
+        coll.create("t", svc.mutable)
+
+        def submit(q, p):
+            coll.submit("t", q, p)
+
+        drain = coll.run_until_idle
+    else:
+        submit, drain = svc.submit, svc.run_until_idle
+
+    def batches(n):
+        for i in range(n):
+            submit(rng.normal(size=d).astype(np.float32),
+                   P.Pred.range(i % 2, 0.0, 0.3 + 0.1 * (i % 3)))
+        drain()
+
+    batches(3)  # registry off: nothing recorded
+    assert obs_reg.registry().get("compass_loop_steps_total") is None
+    ran.clear()
+    obs_reg.set_enabled(True)
+    batches(6)  # a full batch of 4 and one of 2 real lanes and 2 fillers
+    assert [len(r) for r in ran] == [4, 4]
+    counter = obs_reg.registry().get("compass_loop_steps_total")
+    assert sum(s["value"] for s in counter.samples()) == sum(int(r.max()) for r in ran)
+    # the mean over real lanes can only be lower
+    steps = obs_reg.registry().get("compass_steps_total")
+    assert sum(s["value"] for s in steps.samples()) <= 6 * sum(int(r.max()) for r in ran)
+
+
 def test_service_write_error_routing():
     obs_reg.set_enabled(True)
     svc, rng, d, a = _service(mutable=True)

@@ -470,3 +470,36 @@ def test_drop_discards_queued_work_but_keeps_shared_executables():
     a.submit(q, pred)
     svc.flush()
     assert svc.compile_count == n
+
+
+def test_program_texts_name_the_served_programs_and_their_stages(tmp_path):
+    """Each bucket label's compiled program, on the mutable and the AOT path,
+    carries the engine's stage scopes; the service's host phases are spans
+    of a profiler trace around the micro-batch."""
+    import jax
+
+    from repro.obs import profiling as obs_prof
+
+    svc = _svc()
+    svc.create("m", _mut(300, 1))
+    rng = np.random.default_rng(2)
+    svc.create("i", build_index(rng.normal(size=(300, D)).astype(np.float32),
+                                rng.uniform(size=(300, N_ATTRS)).astype(np.float32), CFG))
+    jax.profiler.start_trace(str(tmp_path))
+    for name in ("m", "i"):
+        q, pred = _qp(3)
+        svc.submit(name, q, pred)
+        svc.run_until_idle()
+    jax.profiler.stop_trace()
+    texts = svc.program_texts()
+    assert set(texts) == {"B4xT1"}  # one label: the later, AOT, program wins
+    assert "compass/engine/loop" in texts["B4xT1"]
+    assert "compass/visit_step" in texts["B4xT1"] or "compass/engine/visit" in texts["B4xT1"]
+    assert obs_prof.SERVED.texts()["B4xT1"] == texts["B4xT1"]
+
+    from bench import tracing
+
+    spans = {sp[0] for sp in tracing.read(tracing.find(str(tmp_path)), window_ns=(0, 1 << 62))
+             .spans}
+    assert {"compass/serve/writes", "compass/serve/pack", "compass/serve_batch/B4xT1",
+            "compass/serve/unpack", "compass/serve/gauges"} <= spans

@@ -14,6 +14,8 @@ this file loads the TPU compiler.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -133,36 +135,90 @@ def test_unaligned_width_compiles(one_chip, kernel):
         )
 
 
-def test_served_exact_search_compiles(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def served(one_chip):
     """The served exact program (mutable fan-out, planner on, fused visit
-    kernel) at 2^20 rows fits one chip.  Guards against the vmapped engine
-    broadcasting the corpus per lane into a kernel operand (B copies of
-    the table would not fit in HBM)."""
+    kernel) at 2^20 rows, compiled for one described chip for each term
+    bucket the benchmark serves: ``{T: compiled}``."""
     from repro.compass import CompassParams
     from repro.core import predicate as P
     from repro.core.index import BuildConfig, build_index
     from repro.core.mutable import MutableIndex, mutable_search
     from repro.data.synthetic import make_vector_corpus
 
-    # the engine picks interpret mode from the host platform: steer it
-    for mod in (visit_step, filter_distance, pq_score, ivf_score):
-        monkeypatch.setattr(mod, "default_interpret", lambda: False)
-    n_small = 1500
-    x, attrs, _ = make_vector_corpus(n_small, D, A, n_modes=16, seed=0)
-    snap = MutableIndex(build_index(x, attrs, BuildConfig(nlist=NLIST // 64)),
-                        delta_cap=64).snapshot()
-    n_rows, n_real = snap.index.n_records, 1 << 20
+    with pytest.MonkeyPatch.context() as mp:
+        # the engine picks interpret mode from the host platform: steer it
+        for mod in (visit_step, filter_distance, pq_score, ivf_score):
+            mp.setattr(mod, "default_interpret", lambda: False)
+        n_small = 1500
+        x, attrs, _ = make_vector_corpus(n_small, D, A, n_modes=16, seed=0)
+        snap = MutableIndex(build_index(x, attrs, BuildConfig(nlist=NLIST // 64)),
+                            delta_cap=64).snapshot()
+        n_rows, n_real = snap.index.n_records, 1 << 20
 
-    def real(leaf):
-        grow = {n_rows: n_real, n_rows + 1: n_real + 1}
-        return _spec(one_chip, tuple(grow.get(d, d) for d in leaf.shape), leaf.dtype)
+        def real(leaf):
+            grow = {n_rows: n_real, n_rows + 1: n_real + 1}
+            return _spec(one_chip, tuple(grow.get(d, d) for d in leaf.shape), leaf.dtype)
 
-    pm = CompassParams(k=10, ef=64, planner=True, backend="pallas")
-    pred = P.Predicate(_spec(one_chip, (B, 1, A)), _spec(one_chip, (B, 1, A)))
-    compiled = _compile(
-        lambda i, g, d, q, p: mutable_search(i, g, d, q, p, pm),
-        jax.tree.map(real, snap.index), jax.tree.map(real, snap.base_gids),
-        jax.tree.map(real, snap.delta), _spec(one_chip, (B, D)), pred,
-    )
-    mem = compiled.memory_analysis()
+        pm = CompassParams(k=10, ef=64, planner=True, backend="pallas")
+        out = {}
+        for terms in (1, T):
+            pred = P.Predicate(_spec(one_chip, (B, terms, A)), _spec(one_chip, (B, terms, A)))
+            out[terms] = _compile(
+                lambda i, g, d, q, p: mutable_search(i, g, d, q, p, pm),
+                jax.tree.map(real, snap.index), jax.tree.map(real, snap.base_gids),
+                jax.tree.map(real, snap.delta), _spec(one_chip, (B, D)), pred,
+            )
+        return out
+
+
+def test_served_exact_search_compiles(served):
+    """The served exact program at 2^20 rows fits one chip.  Guards against
+    the vmapped engine broadcasting the corpus per lane into a kernel
+    operand (B copies of the table would not fit in HBM)."""
+    mem = served[1].memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("terms", [1, T])
+def test_served_program_stages_resolve(served, terms):
+    """Every device operation of the served program can be put down to an
+    engine stage by the benchmark's rule (``bench/scopes.py``)."""
+    from bench import scopes
+
+    prog = scopes.Program(served[terms].as_text())
+    assert prog.main_loop is not None
+    stage = {name: prog.stage(name) for name in prog.instrs}
+    # B.NEXT's per-lane copy of a 2^20-wide attribute run, one per lane and
+    # term: a slice loop XLA made from a gather, sent to B.NEXT by the while
+    # that holds it
+    wide = [i for i in prog.instrs.values()
+            if i.opcode == "fusion" and i.shape.startswith(f"f32[{B * terms},1,{1 << 20}]")
+            and prog.in_main_loop(i.name)]
+    assert wide
+    for ins in wide:
+        holder = prog.callers[ins.computation]
+        assert prog.instrs[holder].opcode == "while"
+        assert stage[holder] == stage[ins.name] == "compass/engine/bnext"
+    # the Pallas kernels inside the loop are the fused visit step's calls
+    kernels = re.findall(r'^\s+(?:ROOT\s+)?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
+                         served[terms].as_text(), re.M)
+    in_loop = [n for n in kernels if prog.in_main_loop(n)]
+    assert in_loop and {stage[n] for n in in_loop} == {"compass/visit_step"}
+    assert {stage[n] for n in kernels} <= {"compass/visit_step", "compass/ivf_score",
+                                           "compass/filter_distance"}
+    # every sort is a queue sort, or one of these: the centroid ranking at
+    # OPEN, G.NEXT's two-hop top_k, the delta's top_k, and those XLA makes
+    # for a scatter (inside the loop, held by it; outside it, in no scope)
+    named = {"compass/engine/sort", "compass/engine/open", "compass/engine/gnext",
+             "compass/mutable/delta", "compass/engine/loop"}
+    for name, ins in prog.instrs.items():
+        if ins.opcode == "sort":
+            assert stage[name] in named or (
+                stage[name] == scopes.UNSCOPED and ins.op_name is None
+                and not prog.in_main_loop(name)), (name, stage[name])
+    assert sum(stage[n] == "compass/engine/sort" for n, i in prog.instrs.items()
+               if i.opcode == "sort") >= 10
+    # nothing that runs inside the engine loop, however deeply nested, is
+    # left unscoped
+    assert not [n for n in prog.instrs if prog.in_main_loop(n) and stage[n] == scopes.UNSCOPED]
