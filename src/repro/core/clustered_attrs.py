@@ -12,9 +12,12 @@ identical O(log n + m) semantics with pure array reads:
   sorted_vals[a] : (N,)  f32   — attr_a values in that order
   offsets        : (nlist+1,) int32 — CSR cluster boundaries
 
-A range probe inside cluster ``c`` is a 32-step branchless binary search
-confined to ``[offsets[c], offsets[c+1])`` — the "B+-tree descent" — and the
-run ``order[a][beg:end]`` is the leaf scan.  Updates to attribute values are
+A range probe inside cluster ``c`` is a branchless binary search confined
+to ``[offsets[c], offsets[c+1])`` — the "B+-tree descent" — and the run
+``order[a][beg:end]`` is the leaf scan.  The engine's probe
+(:func:`run_position`) reads one element ``sorted_vals[a, mid]`` of the 2-D
+runs per halving, ``N.bit_length()`` halvings for N records: a range of at
+most N rows is empty after that many.  Updates to attribute values are
 per-cluster re-sorts (cheap, local), mirroring the paper's point that only
 the relational side needs maintenance on attribute update.
 """
@@ -75,7 +78,9 @@ _BSEARCH_ITERS = 32  # supports N up to 2^32
 def searchsorted_slice(vals: jax.Array, lo_idx, hi_idx, x, side: str = "left"):
     """Insertion point of ``x`` within ``vals[lo_idx:hi_idx]`` (global index).
 
-    Branchless fixed-depth binary search; all arguments may be traced.
+    Branchless fixed-depth binary search over one attribute's run ``vals``;
+    all arguments may be traced.  The planner's probe
+    (:func:`run_bounds_all_clusters`); the engine's is :func:`run_position`.
     """
 
     def body(_, bounds):
@@ -92,14 +97,41 @@ def searchsorted_slice(vals: jax.Array, lo_idx, hi_idx, x, side: str = "left"):
     return lo
 
 
+def run_position(vals: jax.Array, attr, lo_idx, hi_idx, x, right=False):
+    """Insertion point of ``x`` within ``vals[attr, lo_idx:hi_idx]`` (global
+    index): the first position whose value is ``>= x`` (``right`` false) or
+    ``> x`` (``right`` true).
+
+    ``vals`` is the ``(A, N)`` runs; every other argument may be traced and
+    they broadcast together, so one call runs many searches side by side.
+    Each halving reads single elements ``vals[attr, mid]`` — under ``vmap``
+    a scalar gather from the unbatched runs, never a per-lane copy of a
+    run — and ``N.bit_length()`` halvings empty any range of at most N rows.
+    """
+    n = vals.shape[1]
+    attr, lo, hi, x, right = jnp.broadcast_arrays(attr, lo_idx, hi_idx, x, right)
+
+    def body(_, bounds):
+        lo, hi = bounds
+        valid = lo < hi
+        mid = (lo + hi) // 2
+        v = vals[attr, jnp.clip(mid, 0, n - 1)]
+        go_right = jnp.where(right, v <= x, v < x)
+        new_lo = jnp.where(go_right, mid + 1, lo)
+        new_hi = jnp.where(go_right, hi, mid)
+        return (jnp.where(valid, new_lo, lo), jnp.where(valid, new_hi, hi))
+
+    lo, _ = jax.lax.fori_loop(0, n.bit_length(), body, (lo, hi))
+    return lo
+
+
 def range_in_cluster(ca: ClusteredAttrs, cluster, attr, lo_val, hi_val):
     """(beg, end) global positions into ``order[attr]`` for records of
     ``cluster`` with attr value in the closed interval [lo_val, hi_val]."""
     c_beg = ca.offsets[cluster]
     c_end = ca.offsets[cluster + 1]
-    vals = ca.sorted_vals[attr]
-    beg = searchsorted_slice(vals, c_beg, c_end, lo_val, side="left")
-    end = searchsorted_slice(vals, c_beg, c_end, hi_val, side="right")
+    beg = run_position(ca.sorted_vals, attr, c_beg, c_end, lo_val)
+    end = run_position(ca.sorted_vals, attr, c_beg, c_end, hi_val, right=True)
     return beg, end
 
 

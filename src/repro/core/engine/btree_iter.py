@@ -7,51 +7,64 @@ on demand, through the ranked-cluster cursor stored in the engine state
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.obs.profiling import staged
 
 from .. import predicate as P
-from ..clustered_attrs import searchsorted_slice
+from ..clustered_attrs import run_position
 from . import state as S
+
+
+def advance(index, pred, chosen, st: S.EngineState, tries: int) -> S.EngineState:
+    """Advance the ranked-cluster cursor when no term has rows left.
+
+    Opens the next clusters of ``rank`` in turn, at most ``tries`` of them,
+    until one holds a row in some term's range, and points the per-term
+    cursors at that cluster's runs.  Running past the last cluster marks
+    the iterator exhausted and leaves the cursors alone.
+
+    All ``tries`` candidate clusters are probed at once (one
+    :func:`run_position` loop over ``(side, try, term)``) and the first
+    non-empty one is picked, which is what opening them one by one gives.
+    Only the four cursor leaves are selected, so no ``lax.cond`` broadcasts
+    the runs or the state to every lane under ``vmap``.
+    """
+    ca = index.cattrs
+    nlist = index.nlist
+    pos = st.rank_pos + jnp.arange(tries)  # (K,) the clusters a try would open
+    opened = pos < nlist
+    c = st.rank[jnp.clip(pos, 0, nlist - 1)]
+    t = jnp.arange(pred.lo.shape[0])
+    x = jnp.stack([pred.lo[t, chosen], pred.hi[t, chosen]])  # (2, T)
+    bounds = run_position(
+        ca.sorted_vals, chosen, ca.offsets[c][:, None], ca.offsets[c + 1][:, None],
+        x[:, None, :], right=jnp.array([False, True])[:, None, None],
+    )  # (2, K, T)
+    beg, end = bounds[0], bounds[1]
+    found = opened & (jnp.sum(jnp.maximum(end - beg, 0), axis=1) > 0)
+    n_opened = jnp.sum(opened)
+    hit = jnp.any(found)
+    # the try that stops: the first non-empty cluster, else the last opened
+    j = jnp.where(hit, jnp.argmax(found), n_opened - 1)
+    rem = jnp.sum(jnp.maximum(st.term_end - st.term_beg, 0))
+    need = (rem == 0) & ~st.b_exhausted
+    move = need & (j >= 0)
+    jc = jnp.maximum(j, 0)
+    return st._replace(
+        rank_pos=jnp.where(move, st.rank_pos + j + 1, st.rank_pos),
+        term_beg=jnp.where(move, beg[jc], st.term_beg),
+        term_end=jnp.where(move, end[jc], st.term_end),
+        b_exhausted=st.b_exhausted | (need & ~hit & (n_opened < tries)),
+    )
 
 
 @staged("engine/bnext")
 def step(index, q, pred, chosen, st: S.EngineState, pm, backend) -> S.EngineState:
     """One B.NEXT pull: fetch up to ``efi`` candidate records and VISIT them."""
     ca = index.cattrs
-    nlist = index.nlist
     T = pred.lo.shape[0]
-
-    def advance_cluster(st: S.EngineState):
-        """Advance the ranked-cluster cursor; point the per-term cursors at
-        the new cluster's per-attribute sorted runs."""
-        exhausted = st.rank_pos >= nlist
-        c = st.rank[jnp.clip(st.rank_pos, 0, nlist - 1)]
-        c_beg, c_end = ca.offsets[c], ca.offsets[c + 1]
-
-        def one_term(t):
-            a = chosen[t]
-            lo_v, hi_v = pred.lo[t, a], pred.hi[t, a]
-            beg = searchsorted_slice(ca.sorted_vals[a], c_beg, c_end, lo_v, "left")
-            end = searchsorted_slice(ca.sorted_vals[a], c_beg, c_end, hi_v, "right")
-            return beg, end
-
-        beg, end = jax.vmap(one_term)(jnp.arange(T))
-        return st._replace(
-            rank_pos=jnp.where(exhausted, st.rank_pos, st.rank_pos + 1),
-            term_beg=jnp.where(exhausted, st.term_beg, beg),
-            term_end=jnp.where(exhausted, st.term_end, end),
-            b_exhausted=st.b_exhausted | exhausted,
-        )
-
-    def maybe_advance(st: S.EngineState):
-        rem = jnp.sum(jnp.maximum(st.term_end - st.term_beg, 0))
-        need = (rem == 0) & ~st.b_exhausted
-        return jax.lax.cond(need, advance_cluster, lambda s: s, st)
-
-    st = jax.lax.fori_loop(0, pm.cluster_tries, lambda _, s: maybe_advance(s), st)
+    st = advance(index, pred, chosen, st, pm.cluster_tries)
 
     # fetch up to efi positions across terms (term-major order)
     rem = jnp.maximum(st.term_end - st.term_beg, 0)  # (T,)
