@@ -43,7 +43,8 @@ ROOT = HERE.parent
 #: run that gets through the pool starts it again
 DEFAULT_POOL = 16384
 #: registry counters that per-layer metrics read, summed over their labels
-COUNTERS = ("compass_queries_total", "compass_steps_total", "compass_dist_total")
+COUNTERS = ("compass_queries_total", "compass_steps_total", "compass_dist_total",
+            "compass_adc_total", "compass_rerank_total")
 BUCKET_FIELDS = ("n_batches", "n_requests", "n_fillers", "total_exec_s",
                  "n_mode_prefilter", "n_mode_cooperative", "n_mode_postfilter")
 
@@ -147,9 +148,6 @@ def main(argv=None, root: pathlib.Path | None = None) -> int:
         sys.path.insert(0, str(ROOT / "src"))
     if args.cpu_rehearsal:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    else:
-        # the one persistent compilation cache, at a fixed path in the checkout
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     import jax
 
     from bench import check, data, spec, system, tracing, window
@@ -166,6 +164,10 @@ def main(argv=None, root: pathlib.Path | None = None) -> int:
         return 2
     peaks = None if args.cpu_rehearsal else spec.peaks(dev.device_kind, root)
     if not args.cpu_rehearsal:
+        # the one persistent compilation cache, at a fixed path in the checkout, set
+        # only once a chip is found: a process that finds none writes no cache there
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
     from repro.compile_cache import configure as configure_compile_cache
@@ -204,7 +206,7 @@ def main(argv=None, root: pathlib.Path | None = None) -> int:
         svc, name = system.build(cfg, x, attrs, args.seed, phases)
     for ev in obs_events.EVENTS.tail(100, kind="index_build_phase"):
         log(f"   build {ev['phase']}: {ev['wall_s']:.3f} s")
-    for ph in ("build_index", "mutable_wrap"):
+    for ph in ("build_index", "quantize_index", "mutable_wrap"):
         if ph in phases:
             log(f"{ph}: {phases[ph]:.3f} s")
     c1 = compiles.snapshot()

@@ -440,6 +440,24 @@ def stage_ms(run, stage: str) -> float | None:
     return att.ms_per_batch(stage, run.buckets["n_batches"])
 
 
+def within_ms(run, stage: str) -> float | None:
+    """Device ms per traced micro-batch of ``stage`` with the stages opened
+    inside it, such as the kernels it calls: the operations whose placing
+    ``op_name`` holds ``stage`` anywhere, not only as the innermost scope.
+    None where nothing can be read."""
+    att = stages(run)
+    if att is None or not run.buckets["n_batches"]:
+        return None
+    scope = re.compile(re.escape(stage) + r"(?=[/)]|$)")
+    total = 0.0
+    for label, name, _, sec, _ in att.ops:
+        prog = att.programs.get(label)
+        via = prog.placed(name)[1] if prog is not None else None
+        if via is not None and scope.search(prog.instrs[via].op_name or ""):
+            total += sec
+    return 1000.0 * total / run.buckets["n_batches"]
+
+
 def _log(msg: str) -> None:
     import jax
 

@@ -1,11 +1,12 @@
 """The system under test, built from a configuration file.
 
-The configuration's ``index``, ``tier``, ``search`` and ``service`` blocks
-are handed to the program's own entry points (``build_index``,
-``MutableIndex``, ``CollectionService``) unchanged; nothing here scores a
-row.  :class:`ControlService` is the
-control of the comparison: the plain reference put in the service's place,
-computed one precision step below what the configurations state.
+The configuration's ``index``, ``quant``, ``tier``, ``search`` and
+``service`` blocks are handed to the program's own entry points
+(``build_index``, ``quantize_index``, ``MutableIndex``,
+``CollectionService``) unchanged; nothing here scores a row.
+:class:`ControlService` is the control of the comparison: the plain
+reference put in the service's place, computed one precision step below
+what the configurations state.
 """
 from __future__ import annotations
 
@@ -19,20 +20,25 @@ import numpy as np
 from . import reference
 
 COLLECTION = "bench"
+#: the keys of a configuration's ``quant`` block, each required
+QUANT_KEYS = ("m", "ks", "iters", "refine_factor", "rerank")
 
 
 def build(config: dict, x: np.ndarray, attrs: np.ndarray, seed: int, phases: dict):
     """``(service, collection name)`` serving ``x``/``attrs`` as ``config``
-    says.  Wall times of the build go into ``phases``."""
+    says.  Wall times of the build go into ``phases``.  A ``quant`` block
+    quantizes the built index (``phases["quantize_index"]``) and serves the
+    collection with its two-stage search."""
     from repro.compass import CompassParams
     from repro.core.index import BuildConfig, build_index
     from repro.core.mutable import MutableIndex
+    from repro.core.quant import QuantConfig, QuantParams, quantize_index
     from repro.serving.tenancy import CollectionService
 
-    ix, tier, search, svc_cfg = config["index"], config["tier"], config["search"], \
-        config["service"]
-    if config.get("quant"):
-        raise ValueError("a quantized tier is not driven by this harness yet")
+    ix, quant, tier, search, svc_cfg = config["index"], config.get("quant"), \
+        config["tier"], config["search"], config["service"]
+    if quant is not None and sorted(quant) != sorted(QUANT_KEYS):
+        raise ValueError(f"a quant block has exactly the keys {QUANT_KEYS}, not {sorted(quant)}")
     if tier["kind"] not in ("mutable", "immutable"):
         raise ValueError(f"unknown tier kind {tier['kind']!r}")
     build_seed = int(seed) % (1 << 31)
@@ -42,6 +48,13 @@ def build(config: dict, x: np.ndarray, attrs: np.ndarray, seed: int, phases: dic
         seed=build_seed))
     jax.block_until_ready(index)
     phases["build_index"] = time.perf_counter() - t0
+    if quant is not None:
+        t0 = time.perf_counter()
+        index = quantize_index(index, QuantConfig(
+            m=int(quant["m"]), ks=int(quant["ks"]), iters=int(quant["iters"]),
+            seed=build_seed), metric=ix["metric"])
+        jax.block_until_ready(index)
+        phases["quantize_index"] = time.perf_counter() - t0
     if tier["kind"] == "mutable":
         t0 = time.perf_counter()
         index = MutableIndex(index, delta_cap=int(tier["delta_cap"]))
@@ -53,7 +66,9 @@ def build(config: dict, x: np.ndarray, attrs: np.ndarray, seed: int, phases: dic
     svc = CollectionService(params, batch_size=int(svc_cfg["batch_size"]),
                             max_wait_s=float(svc_cfg["max_wait_s"]),
                             max_batches_per_step=int(svc_cfg["max_batches_per_step"]))
-    svc.create(COLLECTION, index, cache_capacity=int(svc_cfg["cache_capacity"]))
+    svc.create(COLLECTION, index, cache_capacity=int(svc_cfg["cache_capacity"]),
+               quant=None if quant is None else QuantParams(
+                   refine_factor=int(quant["refine_factor"]), rerank=quant["rerank"]))
     return svc, COLLECTION
 
 

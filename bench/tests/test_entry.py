@@ -60,7 +60,11 @@ def test_benchmark_json_names_and_units():
         assert name.match(c["name"]) and len(c["source"]) <= 200 and len(c["why"]) <= 200
 
 
-@pytest.mark.parametrize("change", [{"quant": {"m": 16, "ks": 256}}, {"tier": {"kind": "lsm"}}])
+QUANT = {"m": 16, "ks": 256, "iters": 10, "refine_factor": 4, "rerank": "full"}
+
+
+@pytest.mark.parametrize("change", [{"quant": {"m": 16, "ks": 256}}, {"tier": {"kind": "lsm"}},
+                                    {"quant": dict(QUANT, nbits=8)}])
 def test_a_configuration_the_harness_cannot_drive_is_refused(change):
     from bench import system
 
@@ -73,3 +77,28 @@ def test_a_metric_without_a_reader_is_an_error(tmp_path):
     root = tiny.write_root(tmp_path)
     with pytest.raises(FileNotFoundError):
         spec.load_reader("absent_metric", root)
+
+
+@pytest.mark.parametrize("real", ["sift1m-exact", "sift1m-pq16"])
+def test_the_build_follows_the_quant_block(monkeypatch, real):
+    """Without a quant block nothing is quantized and the collection searches
+    the full-precision rows, as before; with one, the built index is
+    quantized as the block says and searched in two stages."""
+    import repro.core.quant as quant
+    from bench import data, system
+
+    cfg = tiny.tiny_config("x", real)
+    if cfg["quant"] is None:
+        monkeypatch.setattr(quant, "quantize_index", None)  # never called
+    x, attrs, _ = data.make_corpus(1, cfg["corpus"], 1)
+    phases = {}
+    svc, name = system.build(cfg, x, attrs, 1, phases)
+    col = svc._collections[name]
+    qvecs = col.mutable.snapshot().index.qvecs
+    if cfg["quant"] is None:
+        assert set(phases) == {"build_index", "mutable_wrap"}
+        assert col.params.quant is None and qvecs is None
+    else:
+        assert set(phases) == {"build_index", "quantize_index", "mutable_wrap"}
+        assert col.params.quant == quant.QuantParams(refine_factor=4, rerank="full")
+        assert (qvecs.m, qvecs.ks) == (16, 256)

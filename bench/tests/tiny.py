@@ -12,10 +12,12 @@ import shutil
 from bench import run
 
 REAL = pathlib.Path(__file__).resolve().parents[2]
+#: the real benchmark's quantized cell
+QUANTIZED = "sift1m-pq16.broad"
 
 
-def tiny_config(name: str) -> dict:
-    base = json.loads((REAL / "bench" / "configs" / "sift1m-exact.json").read_text())
+def tiny_config(name: str, real: str = "sift1m-exact") -> dict:
+    base = json.loads((REAL / "bench" / "configs" / f"{real}.json").read_text())
     base["name"] = name
     base["corpus"].update(rows=1000, modes=8)
     base["index"]["nlist"] = 16
@@ -25,9 +27,12 @@ def tiny_config(name: str) -> dict:
 
 
 def write_root(tmp: pathlib.Path) -> pathlib.Path:
-    """Two cells, ``tiny-exact.mixed`` (the real ``broad`` mix) and
-    ``tiny-exact.conj`` (its conjunctions alone), with the real benchmark's
-    metrics and one extra per-layer metric, ``batches_traced``."""
+    """Three cells, ``tiny-exact.mixed`` (the real ``broad`` mix),
+    ``tiny-exact.conj`` (its conjunctions alone) and ``tiny-pq16.mixed`` (the
+    quantized tier, PQ m 16, ks 256, refine factor 4, under ``broad``), with
+    the real benchmark's metrics and one extra per-layer metric,
+    ``batches_traced``.  A metric the real benchmark keeps to the quantized
+    cell is kept to ``tiny-pq16.mixed``; every other applies to all three."""
     bench = json.loads((REAL / "BENCHMARK.json").read_text())
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir(parents=True)
@@ -36,6 +41,8 @@ def write_root(tmp: pathlib.Path) -> pathlib.Path:
     shutil.copy(REAL / "bench" / "peaks.json", tmp / "bench" / "peaks.json")
     (tmp / "bench" / "configs" / "tiny-exact.json").write_text(
         json.dumps(tiny_config("tiny-exact")))
+    (tmp / "bench" / "configs" / "tiny-pq16.json").write_text(
+        json.dumps(tiny_config("tiny-pq16", "sift1m-pq16")))
     traffic = json.loads((REAL / "bench" / "traffic" / "broad.json").read_text())
     traffic["arrivals"]["clients"] = 8
     traffic["pool"] = 256
@@ -44,14 +51,15 @@ def write_root(tmp: pathlib.Path) -> pathlib.Path:
     (tmp / "bench" / "traffic" / "conj.json").write_text(json.dumps(traffic))
     (tmp / "bench" / "metrics" / "batches_traced.py").write_text(
         "def read(run):\n    return float(run.buckets['n_batches']) or None\n")
-    bench["configs"] = [{"name": "tiny-exact", "source": "in-test",
-                         "file": "bench/configs/tiny-exact.json", "reduced": ["corpus"],
-                         "why": "tiny"}]
+    bench["configs"] = [{"name": c, "source": "in-test", "file": f"bench/configs/{c}.json",
+                         "reduced": ["corpus"], "why": "tiny"}
+                        for c in ("tiny-exact", "tiny-pq16")]
     bench["workloads"] = [
-        {"name": f"tiny-exact.{mix}", "config": "tiny-exact", "traffic": mix, "chips": 1,
-         "why": "tiny"} for mix in ("mixed", "conj")]
+        {"name": f"{c}.{mix}", "config": c, "traffic": mix, "chips": 1, "why": "tiny"}
+        for c, mix in (("tiny-exact", "mixed"), ("tiny-exact", "conj"), ("tiny-pq16", "mixed"))]
     for m in bench["end_to_end"] + bench["per_layer"]:
-        m.pop("workloads", None)
+        if m.pop("workloads", None) == [QUANTIZED]:
+            m["workloads"] = ["tiny-pq16.mixed"]
     bench["end_to_end"][1]["workloads"] = ["tiny-exact.mixed"]
     bench["per_layer"].append({"name": "batches_traced", "unit": "batches", "better": "higher",
                                "source": "program_counter", "layer": "front door",
