@@ -24,6 +24,8 @@ best-first loop and does not care which construction produced the graph.
 from __future__ import annotations
 
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
@@ -34,6 +36,31 @@ from repro.obs.events import timed
 
 from .distances import pairwise
 from .kmeans import kmeans
+
+
+#: threads of the host-side candidate pools
+_HOST_WORKERS = min(16, os.cpu_count() or 1)
+
+
+@functools.cache
+def _blas_set_threads():
+    """OpenBLAS's ``openblas_set_num_threads_local`` of the library numpy
+    calls (a numpy wheel's ``numpy.libs``), or None: it sets the BLAS
+    threads of the process and returns the count it replaces.  A product's
+    values do not depend on that count (threads split the rows and columns
+    of the output, never a sum), so it changes the speed only."""
+    import ctypes
+    import glob
+
+    libs = os.path.join(os.path.dirname(np.__file__), os.pardir, "numpy.libs")
+    for path in sorted(glob.glob(os.path.join(libs, "*openblas*"))):
+        try:
+            fn = ctypes.CDLL(path).openblas_set_num_threads_local
+        except (OSError, AttributeError):
+            continue
+        fn.argtypes, fn.restype = [ctypes.c_int], ctypes.c_int
+        return fn
+    return None
 
 
 class GraphIndex(NamedTuple):
@@ -68,11 +95,17 @@ def _topk_neighbors_in_pools(
 
     # Pure numpy: cluster shapes vary per iteration, which would retrigger
     # XLA compilation every cluster; at these pool sizes BLAS is plenty.
+    # Clusters run on a thread pool over one BLAS thread each (numpy and
+    # BLAS release the GIL; many callers of a multi-threaded BLAS contend
+    # and run slower than one); each writes only its own members' rows, so
+    # the order never shows.  Where the BLAS threads cannot be set they run
+    # in turn.
     x2 = (x * x).sum(1)
-    for c in range(kc):
+
+    def one(c):
         mem = members[c]
         if mem.size == 0:
-            continue
+            return
         pool = np.concatenate([members[cc] for cc in near_clusters[c]])
         xy = x[mem] @ x[pool].T
         if metric == "l2":
@@ -86,22 +119,74 @@ def _topk_neighbors_in_pools(
         srt = np.take_along_axis(d, idx, axis=1).argsort(axis=1)
         idx = np.take_along_axis(idx, srt, axis=1)
         cand[mem, :k] = pool[idx]
+
+    set_threads = _blas_set_threads()
+    if set_threads is None:
+        for c in range(kc):
+            one(c)
+        return cand
+    blas_threads = set_threads(1)
+    try:
+        with ThreadPoolExecutor(_HOST_WORKERS) as ex:
+            list(ex.map(one, range(kc)))
+    finally:
+        set_threads(blas_threads)
     return cand
 
 
-@functools.partial(jax.jit, static_argnames=("metric",))
-def _nn_descent_round(x: jax.Array, cand: jax.Array, metric: str) -> jax.Array:
-    """One neighbours-of-neighbours refinement round (batched)."""
+#: rows per NN-descent and prune block at widths up to ``_BLOCK_WIDTH``
+_MAX_BLOCK_ROWS = 1024
+_BLOCK_WIDTH = 128
+#: reached rows a connectivity-repair round samples, up to ``_BLOCK_WIDTH``-d
+_REPAIR_SAMPLE = 4096
+
+
+def _rows_within(max_rows: int, d: int) -> int:
+    """``max_rows`` up to ``_BLOCK_WIDTH``-d, else the largest power of two
+    whose rows of width ``d`` hold no more values than ``max_rows`` rows of
+    ``_BLOCK_WIDTH``: the work and bytes per row grow with the width, so a
+    block of rows shrinks to keep them."""
+    rows = max_rows
+    while rows > 1 and rows * d > max_rows * _BLOCK_WIDTH:
+        rows //= 2
+    return rows
+
+
+def _block_rows(d: int) -> int:
+    """Rows per NN-descent and prune block at width ``d``: 1024 up to 128-d,
+    else no more bytes in the gathered ``(rows, C, d)`` block than 1024 rows
+    of a 128-d corpus hold (a 960-d corpus takes 128).  Every row of a block
+    is computed on its own, so the block size never changes the graph; it
+    bounds the device memory a round takes beside the table."""
+    return _rows_within(_MAX_BLOCK_ROWS, d)
+
+
+def _blocked(block, cand: jax.Array, bs: int, out_cols: int) -> jax.Array:
+    """``block(node_ids, cand_blk)`` over the rows of ``cand`` in blocks of
+    ``bs``, padded with sentinel rows; the padding's rows are dropped."""
+    n, r = cand.shape
+    pad = (-n) % bs
+    ids = jnp.arange(n + pad, dtype=jnp.int32)
+    cand_p = jnp.concatenate([cand, jnp.full((pad, r), n, jnp.int32)], 0)
+    out = jax.lax.map(
+        lambda args: block(*args), (ids.reshape(-1, bs), cand_p.reshape(-1, bs, r))
+    )
+    return out.reshape(-1, out_cols)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("metric", "bs"))
+def _nn_descent_round(xp: jax.Array, cand: jax.Array, metric: str, bs: int) -> jax.Array:
+    """One neighbours-of-neighbours refinement round (batched) over the
+    sentinel-padded table ``xp`` (N + 1, d), in blocks of ``bs`` rows."""
     n, r = cand.shape
     sentinel = n
-    xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)], 0)
 
     def block(node_ids, cand_blk):
         nbrs2 = cand.at[jnp.clip(cand_blk, 0, n - 1)].get(mode="clip")  # (b, r, r)
         nbrs2 = jnp.where(cand_blk[:, :, None] >= n, sentinel, nbrs2)
         pool = jnp.concatenate([cand_blk, nbrs2.reshape(cand_blk.shape[0], -1)], 1)
         vecs = xp[jnp.clip(pool, 0, n)]  # (b, C, d)
-        q = x[node_ids]  # (b, d)
+        q = xp[node_ids]  # (b, d); the padding's rows are dropped
         diff = vecs - q[:, None, :]
         if metric == "l2":
             d = jnp.sum(diff * diff, -1)
@@ -127,31 +212,24 @@ def _nn_descent_round(x: jax.Array, cand: jax.Array, metric: str) -> jax.Array:
         new_cand = jnp.where(jnp.isinf(new_d), sentinel, new_cand)
         return new_cand.astype(jnp.int32)
 
-    bs = 1024
-    pad = (-n) % bs
-    ids = jnp.arange(n + pad, dtype=jnp.int32)
-    cand_p = jnp.concatenate([cand, jnp.full((pad, r), sentinel, jnp.int32)], 0)
-    out = jax.lax.map(
-        lambda args: block(*args),
-        (ids.reshape(-1, bs), cand_p.reshape(-1, bs, r)),
-    )
-    return out.reshape(-1, r)[:n]
+    return _blocked(block, cand, bs, r)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "alpha", "metric"))
-def _robust_prune(x: jax.Array, cand: jax.Array, m: int, alpha: float, metric: str) -> jax.Array:
-    """Vectorized occlusion pruning (HNSW `select_neighbors_heuristic`).
+@functools.partial(jax.jit, static_argnames=("m", "alpha", "metric", "bs"))
+def _robust_prune(xp: jax.Array, cand: jax.Array, m: int, alpha: float, metric: str,
+                  bs: int) -> jax.Array:
+    """Vectorized occlusion pruning (HNSW `select_neighbors_heuristic`) over
+    the sentinel-padded table ``xp`` (N + 1, d), in blocks of ``bs`` rows.
 
     Keep candidate c_i (ascending by distance) iff for every already-kept
     c_j: alpha * d(c_i, c_j) >= d(node, c_i).
     """
     n, r = cand.shape
     sentinel = n
-    xp = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)], 0)
 
     def block(node_ids, cand_blk):
         vecs = xp[jnp.clip(cand_blk, 0, n)]  # (b, r, d)
-        q = x[node_ids]
+        q = xp[node_ids]
         if metric == "l2":
             diff = vecs - q[:, None, :]
             d_node = jnp.sum(diff * diff, -1)
@@ -184,14 +262,7 @@ def _robust_prune(x: jax.Array, cand: jax.Array, m: int, alpha: float, metric: s
         out_kept = jnp.take_along_axis(kept, slot, 1)
         return jnp.where(out_kept, out, sentinel).astype(jnp.int32)
 
-    bs = 1024
-    pad = (-n) % bs
-    ids = jnp.arange(n + pad, dtype=jnp.int32)
-    cand_p = jnp.concatenate([cand, jnp.full((pad, r), sentinel, jnp.int32)], 0)
-    out = jax.lax.map(
-        lambda args: block(*args), (ids.reshape(-1, bs), cand_p.reshape(-1, bs, r))
-    )
-    return out.reshape(-1, m)[:n]
+    return _blocked(block, cand, bs, m)
 
 
 def _add_reverse_edges(neighbors: np.ndarray, m: int) -> np.ndarray:
@@ -243,6 +314,9 @@ def _repair_connectivity(neighbors: np.ndarray, x: np.ndarray, entry: int, metri
     out = np.array(neighbors)
     rng = np.random.default_rng(0)
     x2 = (x * x).sum(1)
+    # the (1024, r_rows) distance block of a round costs what it does at
+    # 128-d: 4096 reached rows up to 128-d, 512 at 960-d
+    r_rows = _rows_within(_REPAIR_SAMPLE, x.shape[1])
 
     reached = np.zeros(n, bool)
 
@@ -263,7 +337,7 @@ def _repair_connectivity(neighbors: np.ndarray, x: np.ndarray, entry: int, metri
         if unreached.size == 0:
             break
         r_nodes = np.where(reached)[0]
-        r_sample = r_nodes[rng.integers(0, r_nodes.size, min(4096, r_nodes.size))]
+        r_sample = r_nodes[rng.integers(0, r_nodes.size, min(r_rows, r_nodes.size))]
         u_sample = unreached[rng.integers(0, unreached.size, min(1024, unreached.size))]
         if metric == "l2":
             dmat = (
@@ -425,26 +499,39 @@ def build_graph(
     n_candidates = n_candidates or max(2 * m, 16)
     n_build_clusters = n_build_clusters or max(8, min(n // 128, 4096))
     phase = lambda name: timed("index_build_phase", phase=name, n_rows=n)
-    with phase("graph_kmeans"):
-        km = kmeans(jnp.asarray(x), n_build_clusters, iters=8, seed=seed, metric=metric)
-        assign = np.asarray(km.assignments)
-    with phase("graph_pools"):
-        cand = _topk_neighbors_in_pools(
-            x, assign, np.asarray(km.centroids), n_candidates, link, metric
-        )
-    xj = jnp.asarray(x)
+    bs = _block_rows(d)
+    xp_s = jax.ShapeDtypeStruct((n + 1, d), jnp.float32)
+    cand_s = jax.ShapeDtypeStruct((n, n_candidates), jnp.int32)
+    with ThreadPoolExecutor(1) as ex:
+        # the device rounds compile on a worker thread beside the k-means
+        # and the host pools: the programs the plain calls would compile
+        nn_round = ex.submit(lambda: _nn_descent_round.lower(xp_s, cand_s, metric, bs).compile())
+        prune = ex.submit(
+            lambda: _robust_prune.lower(xp_s, cand_s, m, prune_alpha, metric, bs).compile())
+        with phase("graph_kmeans"):
+            km = kmeans(jnp.asarray(x), n_build_clusters, iters=8, seed=seed, metric=metric)
+            assign = np.asarray(km.assignments)
+        with phase("graph_pools"):
+            cand = _topk_neighbors_in_pools(
+                x, assign, np.asarray(km.centroids), n_candidates, link, metric
+            )
+        nn_round, prune = nn_round.result(), prune.result()
+    # the one device copy of the table during the rounds: sentinel-padded
+    xp = jnp.asarray(np.concatenate([x, np.zeros((1, d), np.float32)], 0))
     cand_j = jnp.asarray(cand)
     with phase("graph_nn_descent"):
         for _ in range(nn_descent_rounds):
-            cand_j = _nn_descent_round(xj, cand_j, metric)
+            cand_j = nn_round(xp, cand_j)
         cand_j.block_until_ready()
     with phase("graph_prune"):
-        pruned = np.asarray(_robust_prune(xj, cand_j, m, prune_alpha, metric))
+        pruned = np.asarray(prune(xp, cand_j))
+    del xp, cand_j
     with phase("graph_reverse_edges"):
         neighbors = _add_reverse_edges(pruned, m)
     with phase("graph_repair"):
         # medoid entry: point nearest to the global mean
         mean = x.mean(0, keepdims=True)
-        entry = int(np.argmin(np.asarray(pairwise(jnp.asarray(mean), xj, metric))[0]))
+        entry = int(np.argmin(np.asarray(pairwise(jnp.asarray(mean), jnp.asarray(x),
+                                                  metric))[0]))
         neighbors = _repair_connectivity(neighbors, x, entry, metric)
     return GraphIndex(jnp.asarray(neighbors), jnp.asarray(np.int32(entry)))

@@ -22,6 +22,7 @@ fixed-shape gathers of sentinel edges read harmless data that is masked out.
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
@@ -137,17 +138,24 @@ def build_index(vectors: np.ndarray, attrs: np.ndarray, cfg: BuildConfig = Build
         vectors = np.asarray(normalize_rows(vectors))
         cfg = dataclasses.replace(cfg, metric="ip")
     n, d = vectors.shape
-    graph = build_graph(
-        vectors,
-        cfg.m,
-        nn_descent_rounds=cfg.nn_descent_rounds,
-        prune_alpha=cfg.prune_alpha,
-        metric=cfg.metric,
-        seed=cfg.seed,
-    )
+    # the IVF k-means compiles on a worker thread while the graph builds;
+    # it is the same program the plain call would compile
+    with ThreadPoolExecutor(1) as ex:
+        ivf_kmeans = ex.submit(lambda: kmeans.lower(
+            jax.ShapeDtypeStruct((n, d), jnp.float32), cfg.nlist, iters=cfg.kmeans_iters,
+            seed=cfg.seed, metric=cfg.metric).compile())
+        graph = build_graph(
+            vectors,
+            cfg.m,
+            nn_descent_rounds=cfg.nn_descent_rounds,
+            prune_alpha=cfg.prune_alpha,
+            metric=cfg.metric,
+            seed=cfg.seed,
+        )
+        ivf_kmeans = ivf_kmeans.result()
     # each phase's wall time is an ``index_build_phase`` event (obs/events)
     with timed("index_build_phase", phase="ivf_kmeans", n_rows=n):
-        km = kmeans(jnp.asarray(vectors), cfg.nlist, iters=cfg.kmeans_iters, seed=cfg.seed, metric=cfg.metric)
+        km = ivf_kmeans(jnp.asarray(vectors), seed=cfg.seed)
         centroids = np.asarray(km.centroids)
         assign = np.asarray(km.assignments)
     with timed("index_build_phase", phase="runs_and_stats", n_rows=n):

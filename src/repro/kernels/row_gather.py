@@ -24,7 +24,8 @@ This module is that pipeline, written once in the form Mosaic accepts:
     (``table[ids]``) ride in as ordinary ``(rb, W)`` row blocks, whose last
     two dims are the array's own; the scoring, the predicate, the
     tombstone AND and the admission select stay in the kernel.  Both
-    routes score the same rows with the same expressions.
+    routes score the same rows with the same expressions.  The float32
+    rows' gather runs in the stage scope ``compass/row_gather/xla``.
   * **outputs are written in (rb, 1) blocks** of a ``(B, G, rb, 1)`` array
     (the block's last two dims equal the array's, so any ``rb`` lowers),
     and reshaped to ``(B, V)`` outside.
@@ -49,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.obs.profiling import stage_scope
 
 from .ref import chain_sum_m, row_distance
 
@@ -193,7 +196,11 @@ def gather_score(ids, rows, attrs, lane, lo, hi, live=None, *, score: str, emit:
     # as row blocks
     dma = dma_rows(score, rows.shape[1])
     if not dma:
-        gathered = rows[ids].astype(jnp.int32 if score == "adc" else rows.dtype)
+        if score == "adc":
+            gathered = rows[ids].astype(jnp.int32)
+        else:  # float32 rows that cannot be DMA'd: their own stage on the trace
+            with stage_scope("row_gather/xla"):
+                gathered = rows[ids]
         in_specs = [row_block(1), row_block(rows.shape[1])]
         operands = [per_row(ids), per_row(gathered)]
         scratch = []

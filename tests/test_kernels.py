@@ -42,6 +42,7 @@ def test_filter_distance_matches_ref(n, d, a, t, v):
     (1, 50, 8, 2, 1, 16),
     (4, 200, 32, 4, 4, 33),   # non-multiple V
     (3, 100, 17, 3, 2, 8),    # odd dim
+    (2, 60, 960, 4, 4, 21),   # GIST's width: rows gathered by XLA
 ])
 def test_filter_distance_batch_matches_ref(b, n, d, a, t, v):
     """The planner's batched run-scan entry point: per-lane queries and
@@ -276,6 +277,50 @@ def test_dma_route_matches_ref(kernel, metric):
         )
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("with_live", [False, True])
+def test_wide_visit_step_matches_ref(with_live):
+    """At GIST's 960-d the rows are not DMA'd (960 is not a multiple of
+    128) but gathered by XLA; parity stays bitwise, tombstones or not."""
+    from repro.kernels.row_gather import dma_rows
+
+    rng = np.random.default_rng(36)
+    n, d, a, t, v = 60, 960, 4, 4, 21
+    assert not dma_rows("l2", d)
+    vectors, attrs = _mk_corpus(rng, n, d, a)
+    live = jnp.asarray(rng.uniform(size=n + 1) > 0.2) if with_live else None
+    idx = jnp.asarray(rng.integers(0, n + 1, v).astype(np.int32))
+    mask = jnp.asarray(rng.uniform(size=v) > 0.3)
+    q = jnp.asarray(rng.normal(size=d).astype(np.float32))
+    lo = jnp.asarray(rng.uniform(0, 0.5, (t, a)).astype(np.float32))
+    hi = jnp.asarray(rng.uniform(0.5, 1.0, (t, a)).astype(np.float32))
+    got, want = _both_jitted(
+        lambda *z: ops.visit_step(*z, metric="l2"),
+        lambda *z: ref.visit_step_ref(*z, "l2"),
+        vectors, attrs, live, idx, mask, q, lo, hi,
+    )
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("score,width,scoped", [
+    ("l2", 960, True), ("ip", 48, True), ("l2", 128, False), ("adc", 16, False)])
+def test_xla_route_gather_has_its_own_scope(score, width, scoped):
+    """Only float32 rows that cannot be DMA'd are gathered under
+    ``compass/row_gather/xla``: not lane-aligned rows (DMA'd), not PQ codes."""
+    from repro.kernels.row_gather import gather_score
+
+    b, n, a, t, v = 2, 40, 3, 2, 16
+    dtype = jnp.uint8 if score == "adc" else jnp.float32
+    lane = (b, width, 256) if score == "adc" else (b, 1, width)
+    text = jax.jit(lambda *z: gather_score(*z, score=score, emit="admit", rb=8,
+                                           interpret=True)).lower(
+        jax.ShapeDtypeStruct((b, v), jnp.int32), jax.ShapeDtypeStruct((n + 1, width), dtype),
+        jax.ShapeDtypeStruct((n + 1, a), jnp.float32), jax.ShapeDtypeStruct(lane, jnp.float32),
+        jax.ShapeDtypeStruct((b, t, a), jnp.float32), jax.ShapeDtypeStruct((b, t, a), jnp.float32),
+    ).as_text(debug_info=True)
+    assert ("compass/row_gather/xla" in text) == scoped
 
 
 def test_visit_step_vmapped_matches_ref():

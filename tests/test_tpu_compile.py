@@ -135,6 +135,32 @@ def test_unaligned_width_compiles(one_chip, kernel):
         )
 
 
+@pytest.mark.parametrize("kernel", ["visit_step", "filter_distance_batch"])
+def test_wide_rows_compile(one_chip, kernel):
+    """GIST's 960-d rows at 2^20 rows, as the served program holds them: the
+    XLA-gather route's (rb, 960) row blocks fit the kernel's fast memory at
+    the block size a traced call takes, with tombstones as the mutable tier
+    passes them."""
+    from repro.kernels.row_gather import dma_rows
+
+    d, n = 960, 1 << 20
+    assert not dma_rows("l2", d)
+    s = lambda *a: _spec(one_chip, *a)
+    if kernel == "visit_step":
+        def fn(vectors, attrs, live, idx, mask, q, lo, hi):
+            return jax.vmap(lambda i, m, q1: visit_step.visit_step(
+                vectors, attrs, live, i, m, q1, lo, hi, interpret=False))(idx, mask, q)
+
+        _compile(fn, s((n + 1, d)), s((n + 1, A)), s((n + 1,), jnp.bool_),
+                 s((B, V), jnp.int32), s((B, V), jnp.bool_), s((B, d)), s((T, A)), s((T, A)))
+    else:
+        _compile(
+            lambda *z: filter_distance.filter_distance_batch(*z, interpret=False),
+            s((n + 1, d)), s((n + 1, A)), s((B, CAP), jnp.int32), s((B, CAP), jnp.bool_),
+            s((B, d)), s((B, T, A)), s((B, T, A)),
+        )
+
+
 @pytest.fixture(scope="module")
 def served(one_chip):
     """The served exact program (mutable fan-out, planner on, fused visit
