@@ -16,8 +16,10 @@ Faithfulness notes (full discussion in DESIGN.md §Adaptation):
 * The paper's cluster graph G' (§IV.C) is replaced by an exact centroid
   ranking — one MXU matmul at OPEN — consumed through a cursor, preserving
   the on-demand semantics (see index.py docstring).
-* Visited is a plain bool vector (a packed bitmap is a pure memory
-  optimization; noted in DESIGN.md §Perf).
+* Visited is a packed bitmap, one uint32 word per 32 ids
+  (``state.mark_visited`` / ``state.is_visited``): the TPU keeps a
+  ``(B, N+1)`` bool table in a tiling it must relay out on every visit
+  (DESIGN.md §Perf).
 
 The same loop, parameterized by :class:`CompassParams`, also implements the
 paper's baselines and ablations:
@@ -267,7 +269,7 @@ def _search_one(
         gtop=FixedQueue.full(pm.ef_cap, n),
         efs=jnp.int32(pm.efs0),
         res=FixedQueue.full(pm.ef, n),
-        visited=jnp.zeros((n + 1,), bool),
+        visited=jnp.zeros((S.visited_words(n),), jnp.uint32),
         rank=rank,
         rank_pos=jnp.int32(0),
         term_beg=jnp.zeros((T,), jnp.int32),
@@ -287,7 +289,8 @@ def _search_one(
         # top-ef here and retire the query before the loop starts.
         def run_prefilter(s: EngineState) -> EngineState:
             safe = jnp.where(plan.mask, plan.ids, n).astype(jnp.int32)
-            visited = s.visited.at[safe].set(True)
+            # plan.mask is deduplicated and nothing is visited yet
+            visited = S.mark_visited(s.visited, safe, plan.mask)
             passing = plan.passing
             if index.live is not None:  # tombstoned rows stay out of results
                 passing = passing & index.live[safe]

@@ -49,7 +49,7 @@ def expand(index, q, pred, st: S.EngineState, pm, backend) -> S.EngineState:
     npass = P.evaluate(pred, index.attrs[safe]) & valid
     sel = jnp.sum(npass) / jnp.maximum(jnp.sum(valid), 1)
 
-    unvis = valid & ~st.visited[safe]
+    unvis = valid & ~S.is_visited(st.visited, safe)
     wm = w * m
     vl = wm + pm.k2
 
@@ -64,7 +64,7 @@ def expand(index, q, pred, st: S.EngineState, pm, backend) -> S.EngineState:
         valid2 = (nbrs2 < n) & jnp.repeat(valid, m)
         safe2 = jnp.where(valid2, nbrs2, n)
         pass2 = P.evaluate(pred, index.attrs[safe2]) & valid2
-        unvis2 = pass2 & ~st.visited[safe2]
+        unvis2 = pass2 & ~S.is_visited(st.visited, safe2)
         unvis2 = S.dedup_new(nbrs2, unvis2)
         # pick a bounded subset of passing two-hop neighbours
         score = unvis2.astype(jnp.float32)

@@ -79,6 +79,36 @@ def dedup_new(ids: jax.Array, mask: jax.Array) -> jax.Array:
     return mask & ~dup
 
 
+def visited_words(n: int) -> int:
+    """Words of one lane's visited bitmap over the ids ``0..n`` (the
+    sentinel ``n`` has a bit of its own): ``ceil((n + 1) / 32)`` rounded up
+    to a multiple of 1024.  Whole 1024-word tiles let the vmapped
+    ``(B, W)`` table and the flat form its scatter takes share one layout on
+    the TPU; a ``(B, n + 1)`` bool table was relaid out, lane by lane, on
+    every visit (DESIGN.md §Perf)."""
+    return -(-(n + 1) // (32 * 1024)) * 1024
+
+
+def is_visited(visited: jax.Array, ids: jax.Array) -> jax.Array:
+    """Whether each of ``ids`` has its bit set: bit ``id & 31`` of word
+    ``id >> 5``.  Meaningful for ids in ``0..n``; the gather clamps any
+    other, so a caller masks those out."""
+    word = visited[ids >> 5]
+    return ((word >> (ids & 31).astype(jnp.uint32)) & 1) == 1
+
+
+def mark_visited(visited: jax.Array, ids: jax.Array, mask: jax.Array) -> jax.Array:
+    """Set the bits of ``ids`` (in ``0..n``) where ``mask``.
+
+    One scatter-add of the bits into their words.  Adding a bit is OR-ing
+    it only if it is not set yet and no other masked id sets it too, so the
+    caller guarantees: the masked ids are distinct, and none is visited
+    yet.  Masked-out entries add 0 and change nothing, wherever they
+    point."""
+    bits = jnp.where(mask, jnp.uint32(1) << (ids & 31).astype(jnp.uint32), jnp.uint32(0))
+    return visited.at[ids >> 5].add(bits)
+
+
 class SearchStats(NamedTuple):
     n_dist: jax.Array  # full-precision distance computations (paper #Comp;
     # includes the quantized tier's stage-two rerank rows when those read
@@ -118,7 +148,7 @@ class EngineState(NamedTuple):
     gtop: FixedQueue  # graph-internal top queue (width control; unfiltered)
     efs: jax.Array  # progressive search width
     res: FixedQueue  # filtered result queue (the global TopQ of Alg. 1)
-    visited: jax.Array  # (N + 1,) bool
+    visited: jax.Array  # (visited_words(N),) uint32 bitmap over ids 0..N
     # clustered B+-tree iterator state (owned by btree_iter)
     rank: jax.Array  # (nlist,) clusters in centroid-distance order
     rank_pos: jax.Array  # cursor into `rank`
@@ -143,7 +173,7 @@ def visit(index, q, pred, st: EngineState, ids, mask, pm, backend) -> EngineStat
     """
     n = index.n_records
     mask = dedup_new(ids, mask)
-    mask = mask & ~st.visited[ids]
+    mask = mask & ~is_visited(st.visited, ids)
     safe = jnp.where(mask, ids, n).astype(jnp.int32)
     # One fused scoring call per visit batch: distance + DNF predicate +
     # tombstone mask + queue admission.  `dist` feeds the traversal queues
@@ -157,7 +187,7 @@ def visit(index, q, pred, st: EngineState, ids, mask, pm, backend) -> EngineStat
         index, q, pred, safe, mask, pm.metric, fused=pm.fused_visit,
         rows_per_step=pm.shape.visit_rb or None,
     )
-    visited = st.visited.at[safe].set(True)  # sentinel slot absorbs masked
+    visited = mark_visited(st.visited, safe, mask)  # distinct, unvisited
     cand = st.cand.merge(dist, safe)
     gtop = st.gtop.merge(dist, safe)
     res = st.res.merge(admit, safe)

@@ -246,3 +246,32 @@ def test_served_program_stages_resolve(served, terms):
     # nothing that runs inside the engine loop, however deeply nested, is
     # left unscoped
     assert not [n for n in prog.instrs if prog.in_main_loop(n) and stage[n] == scopes.UNSCOPED]
+
+
+@pytest.mark.parametrize("terms", [1, T])
+def test_served_loop_keeps_no_per_lane_row_table(served, terms):
+    """The engine loop carries the visited set as packed words, ``u32[B,
+    W]``, and nothing in it holds a bool row of the corpus per lane: no
+    ``(B, >= 2^20)`` bool array in any layout, flat or not, and no
+    ``dynamic-update-slice`` into a 2^20-wide operand, the lane-by-lane
+    relayout XLA made of a ``(B, N+1)`` bool table on every visit."""
+    from bench import scopes
+
+    prog = scopes.Program(served[terms].as_text())
+    wide, words = 1 << 20, 33_792  # words == state.visited_words(2^20)
+    in_loop = [i for n, i in prog.instrs.items() if prog.in_main_loop(n)]
+
+    def arrays(ins):
+        return [(dt, [int(d) for d in dims.split(",") if d])
+                for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", ins.shape)]
+
+    def per_lane_row(dt, dims):
+        return dt == "pred" and ((B in dims and max(dims) >= wide)
+                                 or (len(dims) == 1 and dims[0] >= B * wide))
+
+    per_lane_bool = [i.name for i in in_loop if any(per_lane_row(*a) for a in arrays(i))]
+    assert not per_lane_bool, (len(per_lane_bool), per_lane_bool[:10])
+    wide_updates = [i.name for i in in_loop if i.opcode == "dynamic-update-slice"
+                    and any(max(dims, default=0) >= wide for _, dims in arrays(i))]
+    assert not wide_updates, wide_updates
+    assert any(("u32", [B, words]) in arrays(i) for i in in_loop)
